@@ -6,8 +6,7 @@ check — batched point decompression, vectorized SHA-512 challenge
 hashes, and one Straus multi-scalar multiply across every signature
 lane — runs in JAX, so validated ingest stops being bounded by host
 cores. The same code compiles for TPU, GPU, and CPU (CI runs it on the
-CPU backend); an optional Pallas kernel accelerates the MSM's field
-multiply where the backend supports it (:mod:`.pallas_msm`).
+CPU backend).
 
 Layering:
 

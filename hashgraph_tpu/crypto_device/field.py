@@ -125,16 +125,6 @@ def sub(a, b):
     return carry(a + (pad - b))
 
 
-def mul(a, b):
-    """Schoolbook 16x16 product with hi/lo column split and 2^256===38
-    fold. Carried inputs required (products must be exact in uint32)."""
-    from . import pallas_msm
-
-    if pallas_msm.enabled():
-        return pallas_msm.fe_mul(a, b)
-    return _mul_jnp(a, b)
-
-
 # Column-assignment matrix for the schoolbook product: half-product
 # (i, j)'s lo lands in column i+j, its hi in column i+j+1. Encoding the
 # anti-diagonal scatter as ONE 0/1 integer matmul compiles and runs far
@@ -148,7 +138,9 @@ for _i in range(LIMBS):
 del _i, _j
 
 
-def _mul_jnp(a, b):
+def mul(a, b):
+    """Schoolbook 16x16 product with hi/lo column split and 2^256===38
+    fold. Carried inputs required (products must be exact in uint32)."""
     # (..., 16, 16) exact products; hi/lo split, then the column matmul
     # and the 2^256 === 38 fold.
     prod = a[..., :, None] * b[..., None, :]

@@ -2,7 +2,9 @@
 
 Loads ``native/build/libconsensus_native.so``, building it on first use when
 a compiler is available (the library is ~1s to compile and has zero
-dependencies). Every entry point has a pure-Python fallback elsewhere in the
+dependencies) and rebuilding it whenever its stamp (host ISA + digest of
+``consensus_native.cpp``) differs, so a run always loads the committed
+source. Every entry point has a pure-Python fallback elsewhere in the
 package, so the framework works without it — the native path exists for host
 throughput: EIP-191 verification is ~20-40x faster per core than the
 pure-Python curve math, and the batch calls release the GIL and fan out over
@@ -27,24 +29,33 @@ _lib: ctypes.CDLL | None = None
 _load_attempted = False
 
 
-def _cpu_tag() -> str:
-    """Fingerprint of this host's ISA extensions: a -march=native build from
-    a different host would SIGILL at the first AVX/ADX instruction, so the
-    artifact is stamped with the builder's tag and rebuilt on mismatch."""
+def _build_tag() -> str:
+    """What the artifact must have been built from: this host's ISA
+    extensions (a -march=native build from a different host would SIGILL
+    at the first AVX/ADX instruction) and the committed source's digest
+    (a ``native/build/`` copied along with a checkout may predate it)."""
     import hashlib
     import platform
 
+    isa = platform.machine()
     try:
         with open("/proc/cpuinfo") as fh:
             for line in fh:
                 if line.startswith("flags"):
-                    return hashlib.sha256(line.encode()).hexdigest()[:16]
+                    isa = hashlib.sha256(line.encode()).hexdigest()[:16]
+                    break
     except OSError:
         pass
-    return platform.machine()
+    with open(_SOURCE, "rb") as fh:
+        source = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return f"{isa} {source}"
 
 
 def _build() -> bool:
+    """Compile to a private temporary, stamp it, then swap it in
+    atomically: a concurrent process never maps a half-written file."""
+    tag = _build_tag()
+    tmp = f"{_DEFAULT_SO}.{os.getpid()}.tmp"
     base = ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-pthread"]
     # -march=native enables ADX/BMI2 (mulx/adcx) codegen for the 256-bit
     # field arithmetic — a large ECDSA win; retry portable if rejected.
@@ -52,30 +63,27 @@ def _build() -> bool:
         try:
             os.makedirs(os.path.dirname(_DEFAULT_SO), exist_ok=True)
             subprocess.run(
-                [*flags, "-o", _DEFAULT_SO, _SOURCE],
+                [*flags, "-o", tmp, _SOURCE],
                 check=True,
                 capture_output=True,
                 timeout=120,
             )
-            try:
-                with open(_DEFAULT_SO + ".cputag", "w") as fh:
-                    fh.write(_cpu_tag())
-            except OSError:
-                pass
+            with open(_DEFAULT_SO + ".tag", "w") as fh:
+                fh.write(tag)
+            os.replace(tmp, _DEFAULT_SO)
             return True
         except Exception:
             continue
     return False
 
 
-def _host_mismatch(path: str) -> bool:
-    """True when the cached artifact was built on a host with different ISA
-    extensions (shared/copied checkout on a heterogeneous fleet)."""
+def _stale(path: str) -> bool:
+    """True unless the artifact's stamp matches this host and source."""
     try:
-        with open(path + ".cputag") as fh:
-            return fh.read().strip() != _cpu_tag()
+        with open(path + ".tag") as fh:
+            return fh.read().strip() != _build_tag()
     except OSError:
-        return False  # untagged artifact: assume portable (pre-tag builds)
+        return True
 
 
 def _load() -> ctypes.CDLL | None:
@@ -85,33 +93,15 @@ def _load() -> ctypes.CDLL | None:
             return _lib
         _load_attempted = True
         path = os.environ.get("HASHGRAPH_TPU_NATIVE", _DEFAULT_SO)
-        if not os.path.exists(path) or (
-            path == _DEFAULT_SO and _host_mismatch(path)
-        ):
-            # Only auto-(re)build the default artifact; an explicit env
-            # override pointing at a missing or foreign file is the
-            # caller's mistake to surface.
-            if path != _DEFAULT_SO or not os.path.exists(_SOURCE) or not _build():
+        # Only auto-(re)build the default artifact; an explicit env
+        # override pointing at a missing file is the caller's mistake.
+        if path == _DEFAULT_SO and os.path.exists(_SOURCE):
+            if (not os.path.exists(path) or _stale(path)) and not _build():
                 return None
         try:
             lib = ctypes.CDLL(path)
         except OSError:
             return None
-        _NEWEST_SYMBOL = "hg_parse_vote_columns"  # bump when the ABI grows
-        if not hasattr(lib, _NEWEST_SYMBOL):
-            # Stale artifact (e.g. a cached build from an older checkout):
-            # rebuild the default path once, else give up.
-            if path != _DEFAULT_SO or not _build():
-                return None
-            try:
-                lib = ctypes.CDLL(path)
-            except OSError:
-                return None
-            if not hasattr(lib, _NEWEST_SYMBOL):
-                # dlopen caches by path, so the reload may return the
-                # SAME stale handle; the rebuilt artifact then only takes
-                # effect in a fresh process — degrade, don't crash.
-                return None
         u8p = ctypes.POINTER(ctypes.c_uint8)
         u64p = ctypes.POINTER(ctypes.c_uint64)
         i64p = ctypes.POINTER(ctypes.c_int64)

@@ -279,13 +279,6 @@ def _close_engine(engine) -> None:
         close()
 
 
-def _shard_map():
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # pre-graduation JAX
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
 class ConsensusFleet:
     """Production topology: a router over scope-sharded engines.
 
@@ -861,7 +854,7 @@ class ConsensusFleet:
             return None
         mesh = Mesh(np.asarray(devs), ("shard",))
         tally = jax.jit(
-            _shard_map()(
+            jax.shard_map(
                 partial(jax.lax.psum, axis_name="shard"),
                 mesh=mesh,
                 in_specs=P("shard", None),

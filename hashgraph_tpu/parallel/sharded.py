@@ -123,13 +123,7 @@ class ShardedPool(ProposalPool):
         v1 = P(axis)  # [P] pool arrays and [D*B] routed batches
         v2 = P(axis, None)  # [P, V] pool arrays and [D*B, L] grids
 
-        # jax.shard_map graduated from jax.experimental in newer JAX;
-        # accept both spellings so the mesh path works across the
-        # versions the fleet actually runs.
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # pre-graduation JAX
-            from jax.experimental.shard_map import shard_map
-        sm = partial(shard_map, mesh=mesh)
+        sm = partial(jax.shard_map, mesh=mesh)
 
         self._sharded_activate = jax.jit(
             sm(

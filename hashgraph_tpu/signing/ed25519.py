@@ -65,6 +65,9 @@ class Ed25519ConsensusSigner(ConsensusSignatureScheme):
       distinct subclass identity);
     - ``False``: force the host path even when the env is set.
 
+    A device request that JAX cannot serve raises ``RuntimeError``; it
+    never degrades to the host path.
+
     Signing and scalar ``verify`` are host-side in every case; the
     backends differ only in who executes the batch equation, never in
     verdicts (PARITY.md "Device-resident verification").
@@ -77,7 +80,8 @@ class Ed25519ConsensusSigner(ConsensusSignatureScheme):
                 if device_verify is None
                 else bool(device_verify)
             )
-            if enabled and _device_backend_usable():
+            if enabled:
+                _require_device_backend()
                 cls = Ed25519DeviceConsensusSigner
         return super().__new__(cls)
 
@@ -218,16 +222,17 @@ class Ed25519ConsensusSigner(ConsensusSignatureScheme):
         return PendingVerdicts(_collect)
 
 
-def _device_backend_usable() -> bool:
-    """Probe (memoized in crypto_device) that the JAX pipeline can run;
-    selection quietly degrades to the host path when it cannot, so
-    setting the env on a jax-less box never breaks verification."""
-    try:
-        from .. import crypto_device
+def _require_device_backend() -> None:
+    """Device verification was asked for (argument or env): refuse to
+    construct when JAX cannot serve it, rather than quietly verifying
+    on the host under the device signer's name."""
+    from .. import crypto_device
 
-        return crypto_device.available()
-    except Exception:
-        return False
+    if not crypto_device.available():
+        raise RuntimeError(
+            "device_verify requested but no JAX backend can serve "
+            "hashgraph_tpu.crypto_device"
+        )
 
 
 class Ed25519DeviceConsensusSigner(Ed25519ConsensusSigner):

@@ -1,21 +1,9 @@
 #!/bin/sh
 # Build the native host runtime into native/build/libconsensus_native.so.
-# The Python wrapper (hashgraph_tpu/native.py) also invokes this lazily when
-# the shared object is missing and a compiler is available.
+# One recipe: the Python loader (hashgraph_tpu/native.py) compiles and
+# stamps the artifact (host ISA + source digest) whenever it is missing
+# or stale; this script just asks it to.
 set -e
-cd "$(dirname "$0")"
-mkdir -p build
-# -march=native lets gcc use ADX/BMI2 (mulx/adcx) for the 256-bit field
-# arithmetic — a large win for ECDSA. Fall back to portable codegen on
-# toolchains that reject the flag.
-if ! g++ -O3 -march=native -fPIC -shared -std=c++17 -pthread \
-    -o build/libconsensus_native.so consensus_native.cpp 2>/dev/null; then
-  g++ -O3 -fPIC -shared -std=c++17 -pthread \
-      -o build/libconsensus_native.so consensus_native.cpp
-fi
-# Stamp the builder's ISA fingerprint: the Python loader rebuilds when a
-# shared checkout lands on a host with different CPU extensions (a foreign
-# -march=native binary would SIGILL).
-grep -m1 '^flags' /proc/cpuinfo 2>/dev/null | sha256sum | cut -c1-16 \
-    > build/libconsensus_native.so.cputag 2>/dev/null || true
-echo "built build/libconsensus_native.so"
+cd "$(dirname "$0")/.."
+python3 -c "import sys; from hashgraph_tpu import native; sys.exit(0 if native.available() else 1)"
+echo "built native/build/libconsensus_native.so"

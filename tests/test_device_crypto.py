@@ -311,6 +311,22 @@ class TestDeviceSeam:
         monkeypatch.setenv("HASHGRAPH_TPU_DEVICE_VERIFY", "0")
         assert type(Ed25519ConsensusSigner(seed)) is Ed25519ConsensusSigner
 
+    def test_unservable_device_request_raises(self, monkeypatch):
+        """Asked-for device verification that JAX cannot serve refuses to
+        construct; it never degrades to the host path."""
+        from hashgraph_tpu import crypto_device
+
+        seed = b"\x42" * 32
+        monkeypatch.setattr(crypto_device, "available", lambda: False)
+        with pytest.raises(RuntimeError, match="device_verify"):
+            Ed25519ConsensusSigner(seed, device_verify=True)
+        monkeypatch.setenv("HASHGRAPH_TPU_DEVICE_VERIFY", "1")
+        with pytest.raises(RuntimeError, match="device_verify"):
+            Ed25519ConsensusSigner(seed)
+        assert type(
+            Ed25519ConsensusSigner(seed, device_verify=False)
+        ) is Ed25519ConsensusSigner
+
     def test_seeded_fuzz_decision_identity(self):
         """Every mutation class the wire can produce, device == host,
         at ONE lane bucket (n=6) so the compile is paid once."""
@@ -589,27 +605,3 @@ def test_hypothesis_fuzz_decision_identity():
         _assert_decision_identical(idents, payloads, sigs)
 
     check()
-
-
-@pytest.mark.parametrize("mode", ["interpret"])
-def test_pallas_field_mul_interpret_parity(monkeypatch, mode):
-    """The optional Pallas kernel, run through the interpreter (the
-    only honest option off-TPU), must match the jnp field core."""
-    from hashgraph_tpu.crypto_device import pallas_msm
-
-    monkeypatch.setenv("HASHGRAPH_TPU_DEVICE_VERIFY_PALLAS", mode)
-    pallas_msm.reset_for_tests()
-    try:
-        if not pallas_msm.enabled():
-            pytest.skip("pallas interpreter unavailable on this backend")
-        rng = random.Random(0x9A)
-        vals_a = [rng.getrandbits(256) for _ in range(8)] + [2**256 - 1]
-        vals_b = [rng.getrandbits(256) for _ in range(8)] + [2**256 - 1]
-        a, b = _limbs(vals_a), _limbs(vals_b)
-        got = np.asarray(pallas_msm.fe_mul(a, b))
-        want = np.asarray(fe._mul_jnp(a, b))
-        assert (got == want).all()
-        assert _carried(got)
-    finally:
-        monkeypatch.delenv("HASHGRAPH_TPU_DEVICE_VERIFY_PALLAS")
-        pallas_msm.reset_for_tests()

@@ -11,7 +11,7 @@ have distinguished from noise cannot convict a later round).
 Corpus archaeology the loader handles (see the BENCHMARKS.md
 "Bench round ↔ BENCH file" table):
 
-- **r01, r06**: driver-wrapped ``{n, cmd, rc, tail, parsed}`` records
+- **r06**: driver-wrapped ``{n, cmd, rc, tail, parsed}`` records
   whose ``parsed`` object is the flat bench line;
 - **r02–r05**: the same wrapper but ``parsed: null`` and a
   FRONT-truncated ``tail`` — the artifact keeps only the line's end.
@@ -25,9 +25,9 @@ Confidence discipline: only entries that are neither recovered nor
 spread-less participate in hard regression verdicts; everything else
 still appears in the trajectory but its comparisons are ``advisory``
 (reported, never failing). That is what keeps the existing trajectory
-free of FALSE regressions — r01's TPU headline vs r05's recovered CPU
-line is a hardware story, not a code regression, and neither point
-carries the evidence to say otherwise.
+free of FALSE regressions: a drop between artifacts from different
+hardware is a hardware story, not a code regression, and such points do
+not carry the evidence to say otherwise.
 
 The device-apply busy-share trajectory (round 11's 66.8% → round 19's
 50.9%) is reconstructed alongside, so the attribution the continuous
@@ -56,7 +56,6 @@ REGRESS_SCHEMA = "hashgraph.bench_regress.v1"
 # liveness). From BENCH_r19 on the artifact number IS the round number,
 # which `_round_for` assumes for any file not pinned here.
 ROUND_FOR_FILE = {
-    "BENCH_r01.json": 1,
     "BENCH_r02.json": 2,
     "BENCH_r03.json": 3,
     "BENCH_r04.json": 4,
