@@ -1,0 +1,14 @@
+"""Signature verify: host seconds of the crypto prepass and collection
+(counter hashgraph_bridge_wire_crypto_seconds_total) per vote replied in
+the window, in microseconds.
+
+Found by name: metric ``verify_us_per_vote.closed`` in BENCHMARK.json."""
+
+
+def _per_vote(ctx, key):
+    v = ctx["votes"]
+    return ctx["counters"][key] / v * 1e6 if v else None
+
+
+def read(ctx):
+    return _per_vote(ctx, "crypto_s")
