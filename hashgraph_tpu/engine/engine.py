@@ -69,6 +69,8 @@ from ..obs import (
     slo_engine,
 )
 from ..obs import (
+    ENGINE_EQUIVOCATION_PROBE_FALLBACKS_TOTAL,
+    ENGINE_EQUIVOCATION_PROBE_ROWS_TOTAL,
     ENGINE_HEALTH_SECONDS,
     ENGINE_LOCK_WAIT_SECONDS,
     ENGINE_PLAN_SECONDS,
@@ -521,6 +523,12 @@ class TpuConsensusEngine(Generic[Scope]):
         self._m_health = self.metrics.histogram(ENGINE_HEALTH_SECONDS)
         self._m_plan = self.metrics.histogram(ENGINE_PLAN_SECONDS)
         self._m_post_apply = self.metrics.histogram(ENGINE_POST_APPLY_SECONDS)
+        self._m_probe_rows = self.metrics.counter(
+            ENGINE_EQUIVOCATION_PROBE_ROWS_TOTAL
+        )
+        self._m_probe_fallbacks = self.metrics.counter(
+            ENGINE_EQUIVOCATION_PROBE_FALLBACKS_TOTAL
+        )
         self._m_suffix_len = self.metrics.histogram(
             CHAIN_SUFFIX_LENGTH, DEFAULT_SIZE_BUCKETS
         )
@@ -3404,7 +3412,19 @@ class TpuConsensusEngine(Generic[Scope]):
         plus the equivocation probe over duplicate-shaped rejections —
         a differing vote_hash from an owner the session already tallied
         becomes a retained evidence pair, with the prior vote recovered
-        from the session's scalar votes or its retained wire chunks."""
+        from the session's scalar votes or its retained wire chunks.
+
+        Whether the retained chunks hold a vote by the row's owner is
+        answered from the record's chain continuity state, not by
+        decoding them: on a ``wire_only`` record whose sync stamp is
+        current, ``wire_seen`` holds every owner of the accepted chain
+        (the dangling guard resyncs it, :meth:`_wire_track_chain` folds
+        in each frame's accepts), so an owner outside it has no prior
+        vote and its row is done — the common late first-time voter on
+        a decided session decodes nothing. The chain is still decoded
+        (once per growth) and walked for an owner inside ``wire_seen``
+        (a redelivery or a real equivocation), on a record the legacy
+        permissive path retained into, and under a stale stamp."""
         if not self._health_live:
             return
         from ..bridge import columnar as C
@@ -3422,34 +3442,45 @@ class TpuConsensusEngine(Generic[Scope]):
         cand = statuses == self._EQUIVOCATION_PROBE_CODES[0]
         for code in self._EQUIVOCATION_PROBE_CODES[1:]:
             cand |= statuses == code
-        for row in np.nonzero(cand)[0].tolist():
-            slot = int(slots[row])
-            record = self._records[slot]
+        probe_rows = np.nonzero(cand)[0].tolist()
+        fallbacks = 0
+        for row in probe_rows:
+            record = self._records[int(slots[row])]
             c = cols[row]
             owner = buf[c[C.COL_OWNER_OFF]:c[C.COL_OWNER_OFF] + c[C.COL_OWNER_LEN]]
-            vote_hash = buf[c[C.COL_HASH_OFF]:c[C.COL_HASH_OFF] + c[C.COL_HASH_LEN]]
             prior = record.votes.get(owner)
-            prior_bytes = None
-            if prior is not None and prior.vote_hash != vote_hash:
-                prior_bytes = prior.encode()
-            elif prior is None:
-                for _seq, chunk in self._decoded_retained(record):
-                    for v in chunk:
-                        if v.vote_owner == owner:
-                            if v.vote_hash != vote_hash:
-                                prior_bytes = v.encode()
-                            break
-                    if prior_bytes is not None:
-                        break
-            if prior_bytes is not None:
+            if prior is None and record.retained_wire and not (
+                record.wire_only
+                and record.wire_sync
+                == (len(record.retained_wire), len(record.scalar_seqs))
+                and owner not in record.wire_seen
+            ):
+                fallbacks += 1
+                prior = next(
+                    (
+                        v
+                        for _seq, chunk in self._decoded_retained(record)
+                        for v in chunk
+                        if v.vote_owner == owner
+                    ),
+                    None,
+                )
+            if prior is None:
+                continue
+            vote_hash = buf[c[C.COL_HASH_OFF]:c[C.COL_HASH_OFF] + c[C.COL_HASH_LEN]]
+            if prior.vote_hash != vote_hash:
                 self.health.note_equivocation(
                     scopes[int(scope_idx[row])],
-                    int(cols[row, C.COL_PID]),
-                    prior_bytes,
+                    int(c[C.COL_PID]),
+                    prior.encode(),
                     buf[int(offsets[row]):int(offsets[row + 1])],
                     owner,
                     now,
                 )
+        self._m_probe_rows.inc(len(probe_rows))
+        self._m_probe_fallbacks.inc(fallbacks)
+        self.tracer.count("engine.equivocation_probe_rows", len(probe_rows))
+        self.tracer.count("engine.equivocation_probe_fallbacks", fallbacks)
 
     def _columnar_apply(
         self,

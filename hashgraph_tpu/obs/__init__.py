@@ -360,6 +360,16 @@ ENGINE_PLAN_SECONDS = "hashgraph_engine_plan_seconds"
 ENGINE_POST_APPLY_SECONDS = "hashgraph_engine_post_apply_seconds"
 GC_COLLECTIONS_TOTAL = "hashgraph_gc_collections_total"
 GC_PAUSE_SECONDS_TOTAL = "hashgraph_gc_pause_seconds_total"
+# The wire path's equivocation probe (engine._wire_admit_health): rows
+# with a duplicate-shaped status it looked at, and those of them it
+# answered by decoding and walking the session's retained wire chain
+# rather than from the record's accepted-owner set.
+ENGINE_EQUIVOCATION_PROBE_ROWS_TOTAL = (
+    "hashgraph_engine_equivocation_probe_rows_total"
+)
+ENGINE_EQUIVOCATION_PROBE_FALLBACKS_TOTAL = (
+    "hashgraph_engine_equivocation_probe_fallbacks_total"
+)
 
 # Process-wide default registry (mirrors tracing.tracer's role).
 registry = MetricsRegistry()
@@ -481,6 +491,8 @@ def _install_well_known(reg: MetricsRegistry) -> None:
         PROFILE_SAMPLES_TOTAL,
         PROFILE_DROPPED_TOTAL,
         PROFILE_OVERHEAD_SECONDS_TOTAL,
+        ENGINE_EQUIVOCATION_PROBE_ROWS_TOTAL,
+        ENGINE_EQUIVOCATION_PROBE_FALLBACKS_TOTAL,
     ):
         reg.counter(name)
     # SLO gauges with registered providers come from the SloEngine bound

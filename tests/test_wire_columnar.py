@@ -938,3 +938,117 @@ class TestPreparedFallbackSentinel:
             assert codes[2] == 241  # undecodable row
         finally:
             server.stop()
+
+
+class TestWireEquivocationProbe:
+    """The wire path's equivocation probe answers "does this session
+    already hold an accepted vote by this owner?" from the record's
+    chain continuity state where that state is current, and decodes the
+    retained chain only for an owner it already holds. Either way the
+    statuses and the minted evidence are those of a walk over the
+    decoded chain."""
+
+    SCOPE = "probe"
+
+    @staticmethod
+    def _counters():
+        from hashgraph_tpu.obs import (
+            ENGINE_EQUIVOCATION_PROBE_FALLBACKS_TOTAL,
+            ENGINE_EQUIVOCATION_PROBE_ROWS_TOTAL,
+        )
+        from hashgraph_tpu.obs import registry
+
+        return (
+            registry.counter(ENGINE_EQUIVOCATION_PROBE_ROWS_TOTAL).value,
+            registry.counter(ENGINE_EQUIVOCATION_PROBE_FALLBACKS_TOTAL).value,
+        )
+
+    def _ingest(self, engine, rows, now):
+        data, offsets = _pack(rows)
+        cols, flags = WC.parse_vote_columns(data, offsets)
+        assert bool(flags.all()), "test rows must be canonical"
+        return list(
+            engine.ingest_wire_columnar(
+                [self.SCOPE], np.zeros(len(rows), np.int64), cols, data,
+                offsets, now,
+            )
+        )
+
+    @pytest.mark.parametrize(
+        "case",
+        ["late_first_voter", "identical_resend", "equivocation",
+         "equivocation_stale_stamp"],
+    )
+    def test_probe_answers_from_continuity_state(self, case):
+        from hashgraph_tpu.engine import TpuConsensusEngine
+        from hashgraph_tpu.errors import StatusCode
+        from hashgraph_tpu.obs.health import HealthMonitor
+        from hashgraph_tpu.obs.registry import MetricsRegistry
+
+        health = HealthMonitor(registry=MetricsRegistry())
+        engine = TpuConsensusEngine(
+            StubConsensusSigner(b"\x5a" * 20), capacity=16, voter_capacity=8,
+            health_monitor=health,
+        )
+        proposal = _proposal("wire-probe", voters=4)
+        engine.ingest_proposals([(self.SCOPE, proposal.clone())], NOW)
+        signers = [StubConsensusSigner(bytes([170 + i]) * 20) for i in range(4)]
+        record = engine._records[engine._index[(self.SCOPE, proposal.proposal_id)]]
+        ok = int(StatusCode.OK)
+
+        if case == "equivocation_stale_stamp":
+            # Two wire accepts, then a scalar accept that decides the
+            # session and leaves the wire sync stamp behind the record.
+            rows = _chain(proposal, signers[:2])
+            assert self._ingest(engine, rows, NOW + 1) == [ok, ok]
+            engine.process_incoming_vote(
+                self.SCOPE,
+                build_vote(proposal, True, signers[2], NOW + 1),
+                NOW + 1,
+            )
+            assert record.wire_sync != (
+                len(record.retained_wire), len(record.scalar_seqs)
+            )
+        else:
+            rows = _chain(proposal, signers[:3])
+            assert self._ingest(engine, rows, NOW + 1) == [ok, ok, ok]
+        assert engine.get_consensus_result(self.SCOPE, proposal.proposal_id)
+        assert record.retained_cache is None
+
+        if case == "late_first_voter":
+            late = build_vote(proposal, True, signers[3], NOW + 2).encode()
+        elif case == "identical_resend":
+            late = rows[0]
+        else:
+            # The first signer validly signs a second, different vote.
+            late = build_vote(proposal, False, signers[0], NOW + 2).encode()
+        probed0, fallbacks0 = self._counters()
+        assert self._ingest(engine, [late], NOW + 2) == [
+            int(StatusCode.ALREADY_REACHED)
+        ]
+        probed1, fallbacks1 = self._counters()
+        assert probed1 - probed0 == 1
+
+        if case == "late_first_voter":
+            # Answered from wire_seen: no decode, no walk, no evidence.
+            assert fallbacks1 - fallbacks0 == 0
+            assert record.retained_cache is None
+            assert health.evidence() == []
+            return
+        assert fallbacks1 - fallbacks0 == 1
+        assert record.retained_cache is not None
+        if case == "identical_resend":
+            assert health.evidence() == []
+            return
+        assert health.evidence() == [
+            {
+                "kind": "equivocation",
+                "offender": signers[0].identity().hex(),
+                "scope": self.SCOPE,
+                "proposal_id": proposal.proposal_id,
+                "detected_at": NOW + 2,
+                "vote_a": rows[0].hex(),
+                "vote_b": late.hex(),
+                "verified": True,
+            }
+        ]
