@@ -35,6 +35,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs import (
+    POOL_ACTIVATE_DISPATCHES_TOTAL,
+    POOL_ACTIVATED_SLOTS_TOTAL,
+    observed_span,
+    registry,
+)
 from ..ops.decide import (
     STATE_ACTIVE,
     STATE_FREE,
@@ -48,6 +54,7 @@ from ..ops.ingest import (
     pack_grid,
     pack_slots,
 )
+from ..tracing import tracer as default_tracer
 
 __all__ = ["ProposalPool", "SlotMeta", "PoolFullError"]
 
@@ -226,6 +233,13 @@ class ProposalPool:
             # the engine must not silently route its dominant fast path to
             # the XLA closed-form kernel instead.
             self.supports_fresh_ingest = False
+        # Spans (``pool.activate``) and counters of slot activation; the
+        # sharded pool adds its routing's.
+        self.tracer = default_tracer
+        self._m_activated = registry.counter(POOL_ACTIVATED_SLOTS_TOTAL)
+        self._m_activate_dispatches = registry.counter(
+            POOL_ACTIVATE_DISPATCHES_TOTAL
+        )
         self._init_device_arrays()
 
         # Host mirrors / bookkeeping.
@@ -627,14 +641,17 @@ class ProposalPool:
         slots = self._free[-count:][::-1]
         del self._free[-count:]
         slots_arr = np.asarray(slots, np.int32)
-        self._dispatch_activate(
-            slots_arr,
-            n,
-            np.asarray(req, np.int32),
-            np.asarray(cap, np.int32),
-            np.asarray(gossip, bool),
-            np.asarray(liveness, bool),
-        )
+        with observed_span(self.tracer, "pool.activate", None, slots=count):
+            self._dispatch_activate(
+                slots_arr,
+                n,
+                np.asarray(req, np.int32),
+                np.asarray(cap, np.int32),
+                np.asarray(gossip, bool),
+                np.asarray(liveness, bool),
+            )
+        self._m_activated.inc(count)
+        self._m_activate_dispatches.inc()
 
         expiry = np.asarray(expiry, np.int64)
         created_at = np.asarray(created_at, np.int64)

@@ -102,6 +102,8 @@ hashgraph_bridge_lane_wait_seconds              histogram  bridge serial lane (a
 hashgraph_engine_lock_wait_seconds              histogram  engine lock acquire (frame-level entry points, outermost call)
 hashgraph_engine_{resolve,validate,health,plan,post_apply}_seconds  histogram  wire apply sub-stages (per frame)
 hashgraph_gc_{collections,pause_seconds}_total  gauge      Python GC hook (scrape-time; see install_gc_telemetry)
+hashgraph_pool_{activated_slots,activate_dispatches}_total  counter  proposal pool (slots activated / activation dispatches)
+hashgraph_pool_shard_{rows,grid_rows}_total     counter    sharded pool routing (real rows / D x B rows placed)
 ==============================================  =========  ==================
 
 The table above is machine-readable: :func:`documented_families` parses it
@@ -119,7 +121,10 @@ the device's operations. Per frame: ``bridge.wire_decode``,
 ``engine.wire_validate``, ``engine.health`` (stat ``pass``),
 ``engine.plan_dispatch``, ``engine.device_ingest``, ``engine.post_apply``;
 and ``python.gc`` (stat ``generation``) for every collection once
-:func:`install_gc_telemetry` ran.
+:func:`install_gc_telemetry` ran. Per pool dispatch: ``pool.activate``
+(stat ``slots``) around a slot activation, and on the sharded pool
+``pool.shard_route`` (stats ``rows`` and ``grid_rows``) around the host
+routing and placement of each routed batch.
 """
 
 from __future__ import annotations
@@ -370,6 +375,14 @@ ENGINE_EQUIVOCATION_PROBE_ROWS_TOTAL = (
 ENGINE_EQUIVOCATION_PROBE_FALLBACKS_TOTAL = (
     "hashgraph_engine_equivocation_probe_fallbacks_total"
 )
+# The proposal pool's slot activation (ProposalPool.allocate_batch):
+# slots activated and device dispatches that activated them; and the
+# sharded pool's host routing: real rows routed to their devices and the
+# [D*B] grid rows placed for them (the difference is padding).
+POOL_ACTIVATED_SLOTS_TOTAL = "hashgraph_pool_activated_slots_total"
+POOL_ACTIVATE_DISPATCHES_TOTAL = "hashgraph_pool_activate_dispatches_total"
+POOL_SHARD_ROWS_TOTAL = "hashgraph_pool_shard_rows_total"
+POOL_SHARD_GRID_ROWS_TOTAL = "hashgraph_pool_shard_grid_rows_total"
 
 # Process-wide default registry (mirrors tracing.tracer's role).
 registry = MetricsRegistry()
@@ -493,6 +506,10 @@ def _install_well_known(reg: MetricsRegistry) -> None:
         PROFILE_OVERHEAD_SECONDS_TOTAL,
         ENGINE_EQUIVOCATION_PROBE_ROWS_TOTAL,
         ENGINE_EQUIVOCATION_PROBE_FALLBACKS_TOTAL,
+        POOL_ACTIVATED_SLOTS_TOTAL,
+        POOL_ACTIVATE_DISPATCHES_TOTAL,
+        POOL_SHARD_ROWS_TOTAL,
+        POOL_SHARD_GRID_ROWS_TOTAL,
     ):
         reg.counter(name)
     # SLO gauges with registered providers come from the SloEngine bound
@@ -720,7 +737,8 @@ class observed_span:
     record a context-tagged child span into :data:`trace_store` — this is
     how engine/bridge/WAL spans join a cross-peer causal trace without any
     per-site wiring. A site that learns inside the block that its time
-    belongs to no sink sets ``span.sink = None`` on the ``with`` target.
+    belongs to no sink sets ``span.sink = None`` on the ``with`` target;
+    one that learns a stat there adds it with :meth:`note`.
 
     One perf_counter pair, a no-op TraceMe and one contextvar read when
     nothing is listening (about a microsecond): for per-frame and
@@ -743,6 +761,13 @@ class observed_span:
         self._annotation = annotation
         self._start = time.perf_counter()
         return self
+
+    def note(self, **attrs) -> None:
+        """Add stats learned inside the block, to ``attrs`` and to the
+        open trace annotation."""
+        self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
 
     def __exit__(self, exc_type, exc, tb) -> None:
         duration = time.perf_counter() - self._start

@@ -33,6 +33,7 @@ gauges and JSON serializations sane.
 from __future__ import annotations
 
 import math
+import sys
 from collections import deque
 
 # Below this many observed inter-arrival samples the distribution is not
@@ -46,6 +47,11 @@ DEFAULT_MAX_PHI = 64.0
 # max(observed, min_stddev, rel_stddev * mean).
 DEFAULT_MIN_STDDEV = 0.5
 DEFAULT_REL_STDDEV = 0.1
+# Silence and mean are differences and running sums of clock readings, so
+# each carries rounding of a few ulps of those readings' magnitude. An
+# excess of silence over the mean within that error is no excess: without
+# this, rounding alone would step phi from 0 to -log10(1/2) at x = 0.
+_CLOCK_EPS = 64 * sys.float_info.epsilon
 
 _SQRT2 = math.sqrt(2.0)
 _LN10 = math.log(10.0)
@@ -167,10 +173,13 @@ class PhiAccrual:
         if silence <= 0.0:
             return 0.0
         mean = self.mean()
+        excess = silence - mean
+        if excess <= _CLOCK_EPS * (abs(now) + self._sum):
+            return 0.0
         stddev = max(
             self.stddev(), self.min_stddev, self.rel_stddev * mean
         )
-        return phi_from_deviation((silence - mean) / stddev, self.max_phi)
+        return phi_from_deviation(excess / stddev, self.max_phi)
 
     def reset(self) -> None:
         self.last_heartbeat = None

@@ -45,6 +45,12 @@ from ..ops.ingest import (
     pack_slots,
     unpack_slots,
 )
+from ..obs import (
+    POOL_SHARD_GRID_ROWS_TOTAL,
+    POOL_SHARD_ROWS_TOTAL,
+    observed_span,
+    registry,
+)
 from .mesh import PROPOSAL_AXIS, consensus_mesh
 from ..engine.pool import (
     ProposalPool,
@@ -88,6 +94,8 @@ class ShardedPool(ProposalPool):
         self.axis = self.mesh.axis_names[0]
         self.n_devices = self.mesh.devices.size
         self.local_capacity = capacity_per_device
+        self._m_shard_rows = registry.counter(POOL_SHARD_ROWS_TOTAL)
+        self._m_shard_grid_rows = registry.counter(POOL_SHARD_GRID_ROWS_TOTAL)
         self._build_sharded_kernels()
         super().__init__(capacity_per_device * self.n_devices, voter_capacity)
         # Round-robin free list across devices: pops yield device 0, 1, ...,
@@ -233,12 +241,38 @@ class ShardedPool(ProposalPool):
         spec = P(self.axis) if arr.ndim == 1 else P(self.axis, None)
         return jax.device_put(arr, NamedSharding(self.mesh, spec))
 
+    def _local_ids(self, slots: np.ndarray) -> tuple[np.ndarray, int]:
+        """A payload of the slots' device-local ids, padded with the
+        per-device sentinel: the slot grid as :meth:`_place` routes it."""
+        return (slots % self.local_capacity).astype(np.int32), self.local_capacity
+
+    def _place(
+        self,
+        slots: np.ndarray,
+        payloads: list[tuple[np.ndarray, object]],
+        bucket: int | None = None,
+    ) -> tuple[list[jax.Array], np.ndarray, int]:
+        """:meth:`_route` the payloads, then :meth:`_put_batch` each, under
+        one ``pool.shard_route`` span: (device arrays [D*B, ...], flat
+        positions, bucket B). Counts the real rows routed and the D×B grid
+        rows placed for them."""
+        with observed_span(self.tracer, "pool.shard_route", None) as span:
+            _, routed, rows, bucket = self._route(slots, payloads, bucket)
+            placed = [self._put_batch(a) for a in routed]
+            grid_rows = self.n_devices * bucket
+            span.note(rows=len(slots), grid_rows=grid_rows)
+        self._m_shard_rows.inc(len(slots))
+        self._m_shard_grid_rows.inc(grid_rows)
+        return placed, rows, bucket
+
     # ── Dispatch overrides ─────────────────────────────────────────────
 
     def _dispatch_activate(self, slots, n, req, cap, gossip, liveness) -> None:
-        slot_grid, (n_g, req_g, cap_g, go_g, li_g), _, _ = self._route(
-            slots.astype(np.int64),
-            [(n, 0), (req, 0), (cap, 0), (gossip, False), (liveness, False)],
+        slots = slots.astype(np.int64)
+        routed, _, _ = self._place(
+            slots,
+            [self._local_ids(slots), (n, 0), (req, 0), (cap, 0),
+             (gossip, False), (liveness, False)],
         )
         (
             self._state, self._yes, self._tot, self._vote_mask,
@@ -248,18 +282,15 @@ class ShardedPool(ProposalPool):
             self._state, self._yes, self._tot, self._vote_mask,
             self._vote_val, self._n, self._req, self._cap,
             self._gossip, self._liveness,
-            self._put_batch(slot_grid),
-            self._put_batch(n_g),
-            self._put_batch(req_g),
-            self._put_batch(cap_g),
-            self._put_batch(go_g),
-            self._put_batch(li_g),
+            *routed,
         )
 
     def _dispatch_load(self, slots, state, yes, tot, mask_rows, val_rows) -> None:
-        slot_grid, (st_g, y_g, t_g, m_g, v_g), _, _ = self._route(
-            slots.astype(np.int64),
+        slots = slots.astype(np.int64)
+        routed, _, _ = self._place(
+            slots,
             [
+                self._local_ids(slots),
                 (state, 0),
                 (yes, 0),
                 (tot, 0),
@@ -271,19 +302,13 @@ class ShardedPool(ProposalPool):
             self._state, self._yes, self._tot, self._vote_mask, self._vote_val,
         ) = self._sharded_load(
             self._state, self._yes, self._tot, self._vote_mask, self._vote_val,
-            self._put_batch(slot_grid),
-            self._put_batch(st_g),
-            self._put_batch(y_g),
-            self._put_batch(t_g),
-            self._put_batch(m_g),
-            self._put_batch(v_g),
+            *routed,
         )
 
     def _dispatch_release(self, slots) -> None:
-        slot_grid, _, _, _ = self._route(slots.astype(np.int64), [])
-        self._state = self._sharded_release(
-            self._state, self._put_batch(slot_grid)
-        )
+        slots = slots.astype(np.int64)
+        (slot_grid,), _, _ = self._place(slots, [self._local_ids(slots)])
+        self._state = self._sharded_release(self._state, slot_grid)
 
     def _dispatch_ingest(self, slot_pack, grid_pack):
         """Route the packed batch to owning devices; non-blocking. Returns
@@ -311,7 +336,7 @@ class ShardedPool(ProposalPool):
         local_pack = pack_slots(
             (slots_g % self.local_capacity).astype(np.int32), expired
         )
-        _, (pack_g, grid_g), rows, bucket = self._route(
+        (pack_g, grid_g), rows, bucket = self._place(
             slots_g.astype(np.int64),
             [
                 (local_pack, self.local_capacity),
@@ -326,8 +351,8 @@ class ShardedPool(ProposalPool):
             self._state, self._yes, self._tot, self._vote_mask,
             self._vote_val, self._n, self._req, self._cap,
             self._gossip, self._liveness,
-            self._put_batch(pack_g),
-            self._put_batch(grid_g),
+            pack_g,
+            grid_g,
         )
         return out, rows - row_offset * bucket
 
@@ -343,10 +368,11 @@ class ShardedPool(ProposalPool):
         )
 
     def _dispatch_timeout(self, slots) -> np.ndarray:
-        slot_grid, _, rows, _ = self._route(slots.astype(np.int64), [])
+        slots = slots.astype(np.int64)
+        (slot_grid,), rows, _ = self._place(slots, [self._local_ids(slots)])
         self._state, row_state = self._sharded_timeout(
             self._state, self._yes, self._tot, self._n, self._req,
-            self._liveness, self._put_batch(slot_grid),
+            self._liveness, slot_grid,
         )
         return np.asarray(row_state)[rows]
 

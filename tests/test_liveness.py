@@ -105,6 +105,31 @@ def test_phi_accrual_jitter_earns_wider_tolerance():
     assert regular.phi(now_r + 10.5) < DEFAULT_PHI_THRESHOLD
 
 
+def test_phi_accrual_silence_at_mean_is_not_suspicious():
+    """Silence equal to the mean is x = 0 in exact arithmetic. Clock
+    rounding that puts it a few ulps past the mean must not step phi from
+    0 to -log10(1/2), on a jittery history or on a regular one."""
+    mean = 51.99997810579886
+    d = 0.75 * mean
+    regular, jittery = PhiAccrual(), PhiAccrual()
+    now_r = now_j = 0.0
+    regular.heartbeat(now_r)
+    jittery.heartbeat(now_j)
+    for _ in range(20):
+        for gap in (mean, mean):
+            now_r += gap
+            regular.heartbeat(now_r)
+        for gap in (mean - d, mean + d):
+            now_j += gap
+            jittery.heartbeat(now_j)
+    # now_j + mean - now_j rounds to a silence 2.3e-13 past the mean.
+    assert (now_j + mean) - now_j > jittery.mean()
+    assert jittery.phi(now_j + mean) == 0.0
+    assert regular.phi(now_r + mean) == 0.0
+    # One tick past the mean is a real excess, and phi rises above 0.
+    assert jittery.phi(now_j + mean + 1.0) > 0.0
+
+
 # ── watchdog integration (stale OR phi, read-time grading) ─────────────
 
 

@@ -19,7 +19,7 @@ import pytest
 
 hypothesis = pytest.importorskip("hypothesis")
 
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 
 from hashgraph_tpu.obs.accrual import (  # noqa: E402
     DEFAULT_MAX_PHI,
@@ -86,6 +86,10 @@ def test_phi_resets_on_heartbeat(history, silence):
                  allow_nan=False, allow_infinity=False),
        st.integers(min_value=8, max_value=32),
        silences)
+# Silence equal to the mean, where clock rounding alone once put the wide
+# history a hair past its mean and stepped its phi to -log10(1/2).
+@example(mean=51.99997810579886, spread=0.75, n=20,
+         silence=51.99997810579886)
 def test_phi_monotone_in_spread_at_equal_mean(mean, spread, n, silence):
     """Alternating mean±d histories: same mean, wider d -> phi no higher
     at the same silence (the effective stddev floor keeps this true even
