@@ -1285,8 +1285,10 @@ class TpuConsensusEngine(Generic[Scope]):
         chains with >1 votes through one vmapped device chain kernel — then
         each proposal replays the exact scalar check sequence with the
         precomputed verdicts injected, so error precedence is identical to
-        the scalar path. Returns one StatusCode per item (OK = registered;
-        events emitted exactly as the scalar path would). ``configs``
+        the scalar path; the slots those items claim are activated on the
+        device in one dispatch (``ProposalPool.gather_activations``).
+        Returns one StatusCode per item (OK = registered; events emitted
+        exactly as the scalar path would). ``configs``
         optionally supplies a per-item explicit config override (same
         precedence as create_proposal's; None entries resolve from the
         scope config) — WAL replay uses it to preserve logged overrides.
@@ -1377,56 +1379,57 @@ class TpuConsensusEngine(Generic[Scope]):
         if pending_verify is not None:
             verdicts, vote_hashes = pending_verify.collect()
 
-        for i, (scope, proposal) in enumerate(items):
-            if (scope, proposal.proposal_id) in self._index or self._tier_has(
-                scope, proposal.proposal_id
-            ):
-                # Demoted sessions exist: this path's strict
-                # no-redelivery contract rejects without paging them in.
-                statuses[i] = int(StatusCode.PROPOSAL_ALREADY_EXIST)
-                continue
-            if spans[i] is None:
-                # Nothing precomputed for this item: expired at entry
-                # (the inline gauntlet below raises ProposalExpired
-                # before any signature work), or registered at entry but
-                # freed mid-batch by an earlier item's per-scope-cap
-                # eviction — either way, run the full scalar gauntlet, as
-                # a sequential process_incoming_proposal would.
-                sv = ch = None
-                chain_error = COMPUTE_CHAIN
-            else:
-                start, count = spans[i]
-                sv = verdicts[start : start + count] if count else None
-                ch = vote_hashes[start : start + count] if count else None
-                chain_error = chain_errors.get(i)
-            try:
-                config = self._resolve_config(
-                    scope, configs[i] if configs is not None else None, proposal
-                )
-                session, transition = ConsensusSession.from_proposal(
-                    proposal.clone(),
-                    self._scheme,
-                    config,
-                    now,
-                    sig_verdicts=sv,
-                    chain_error=chain_error,
-                    computed_hashes=ch,
-                )
-                if transition.is_reached and self._owns_replicated_event():
-                    self._emit(
-                        scope,
-                        ConsensusReached(
-                            proposal_id=proposal.proposal_id,
-                            result=transition.reached,
-                            timestamp=now,
-                        ),
+        with self._pool.gather_activations():
+            for i, (scope, proposal) in enumerate(items):
+                if (scope, proposal.proposal_id) in self._index or self._tier_has(
+                    scope, proposal.proposal_id
+                ):
+                    # Demoted sessions exist: this path's strict
+                    # no-redelivery contract rejects without paging them in.
+                    statuses[i] = int(StatusCode.PROPOSAL_ALREADY_EXIST)
+                    continue
+                if spans[i] is None:
+                    # Nothing precomputed for this item: expired at entry
+                    # (the inline gauntlet below raises ProposalExpired
+                    # before any signature work), or registered at entry but
+                    # freed mid-batch by an earlier item's per-scope-cap
+                    # eviction — either way, run the full scalar gauntlet, as
+                    # a sequential process_incoming_proposal would.
+                    sv = ch = None
+                    chain_error = COMPUTE_CHAIN
+                else:
+                    start, count = spans[i]
+                    sv = verdicts[start : start + count] if count else None
+                    ch = vote_hashes[start : start + count] if count else None
+                    chain_error = chain_errors.get(i)
+                try:
+                    config = self._resolve_config(
+                        scope, configs[i] if configs is not None else None, proposal
                     )
-                self._register_session(scope, session, now)
-                self._note_chain_admitted(proposal.votes, config, now)
-            except ConsensusError as exc:
-                statuses[i] = int(exc.code)
-                if exc.code == StatusCode.PROPOSAL_EXPIRED:
-                    self._note_expired_proposal(proposal, now)
+                    session, transition = ConsensusSession.from_proposal(
+                        proposal.clone(),
+                        self._scheme,
+                        config,
+                        now,
+                        sig_verdicts=sv,
+                        chain_error=chain_error,
+                        computed_hashes=ch,
+                    )
+                    if transition.is_reached and self._owns_replicated_event():
+                        self._emit(
+                            scope,
+                            ConsensusReached(
+                                proposal_id=proposal.proposal_id,
+                                result=transition.reached,
+                                timestamp=now,
+                            ),
+                        )
+                    self._register_session(scope, session, now)
+                    self._note_chain_admitted(proposal.votes, config, now)
+                except ConsensusError as exc:
+                    statuses[i] = int(exc.code)
+                    if exc.code == StatusCode.PROPOSAL_EXPIRED:
+                        self._note_expired_proposal(proposal, now)
         return statuses
 
     # ── Gossip delivery: create-or-extend (chain-prefix watermark) ─────

@@ -26,6 +26,7 @@ Design notes (TPU):
 from __future__ import annotations
 
 import os
+from contextlib import contextmanager
 from dataclasses import dataclass
 from functools import partial
 from typing import Hashable
@@ -240,6 +241,9 @@ class ProposalPool:
         self._m_activate_dispatches = registry.counter(
             POOL_ACTIVATE_DISPATCHES_TOTAL
         )
+        # Activation device writes held by gather_activations (None outside
+        # the scope): (slots, n, req, cap, gossip, liveness) per call.
+        self._gathered: list[tuple[np.ndarray, ...]] | None = None
         self._init_device_arrays()
 
         # Host mirrors / bookkeeping.
@@ -604,6 +608,43 @@ class ProposalPool:
 
     # ── Allocation ─────────────────────────────────────────────────────
 
+    @contextmanager
+    def gather_activations(self):
+        """Gather the slot activations made inside the scope into one dispatch.
+
+        Inside the scope :meth:`allocate_batch` does its host work at once
+        (the same slots from the free list, the state and expiry mirrors,
+        :class:`SlotMeta`) and holds back its device write. The held writes
+        go to the device as ONE activation dispatch before any other device
+        operation of the pool (ingest, load, release, timeout, reads) and
+        when the scope closes, so the device sees its writes in the same
+        order as when each call dispatches at once. An inner scope defers
+        to the outer one."""
+        if self._gathered is not None:
+            yield
+            return
+        self._gathered = []
+        try:
+            yield
+        finally:
+            try:
+                self._flush_activations()
+            finally:
+                self._gathered = None
+
+    def _flush_activations(self) -> None:
+        """Dispatch the activations gathered so far, if any, as one."""
+        if self._gathered:
+            parts = [np.concatenate(col) for col in zip(*self._gathered)]
+            self._gathered.clear()
+            self._activate(*parts)
+
+    def _activate(self, slots, n, req, cap, gossip, liveness) -> None:
+        with observed_span(self.tracer, "pool.activate", None, slots=len(slots)):
+            self._dispatch_activate(slots, n, req, cap, gossip, liveness)
+        self._m_activated.inc(len(slots))
+        self._m_activate_dispatches.inc()
+
     def allocate_batch(
         self,
         keys: list[Hashable],
@@ -615,7 +656,8 @@ class ProposalPool:
         expiry: np.ndarray,
         created_at: np.ndarray,
     ) -> list[int]:
-        """Claim one slot per key and initialise its on-device config.
+        """Claim one slot per key and initialise its on-device config
+        (held for one dispatch inside :meth:`gather_activations`).
 
         ``req``/``cap`` are host-precomputed (exact integer threshold math,
         reference: src/utils.rs:307-313 — see ops.decide.required_votes_np).
@@ -626,7 +668,7 @@ class ProposalPool:
         if count == 0:
             return []
         self._check_no_inflight("allocate_batch")
-        n = np.asarray(n, np.int32)
+        n = np.array(n, np.int32)
         if int(n.max()) > self.voter_capacity:
             raise ValueError(
                 f"expected_voters_count {int(n.max())} exceeds pool "
@@ -641,17 +683,19 @@ class ProposalPool:
         slots = self._free[-count:][::-1]
         del self._free[-count:]
         slots_arr = np.asarray(slots, np.int32)
-        with observed_span(self.tracer, "pool.activate", None, slots=count):
-            self._dispatch_activate(
-                slots_arr,
-                n,
-                np.asarray(req, np.int32),
-                np.asarray(cap, np.int32),
-                np.asarray(gossip, bool),
-                np.asarray(liveness, bool),
-            )
-        self._m_activated.inc(count)
-        self._m_activate_dispatches.inc()
+        # Copies: a held write must not alias arrays the caller may reuse.
+        write = (
+            slots_arr,
+            n,
+            np.array(req, np.int32),
+            np.array(cap, np.int32),
+            np.array(gossip, bool),
+            np.array(liveness, bool),
+        )
+        if self._gathered is None:
+            self._activate(*write)
+        else:
+            self._gathered.append(write)
 
         expiry = np.asarray(expiry, np.int64)
         created_at = np.asarray(created_at, np.int64)
@@ -679,6 +723,7 @@ class ProposalPool:
         if not slots:
             return
         self._check_no_inflight("load_rows")
+        self._flush_activations()
         self._dispatch_load(
             np.asarray(slots, np.int32),
             np.asarray(state, np.int32),
@@ -697,6 +742,7 @@ class ProposalPool:
         if not slots:
             return
         self._check_no_inflight("release")
+        self._flush_activations()
         self._dispatch_release(np.asarray(slots, np.int32))
         self._retire_lanes(np.asarray(slots, np.int64))
         for slot in slots:
@@ -845,6 +891,7 @@ class ProposalPool:
         the within-slot arrival index — the fresh assignment rule — so the
         lane plane need not cross the link at all (laneless uint8 cells,
         half the uint16 upload)."""
+        self._flush_activations()
         s_count = len(uniq)
         depth = max(int(depth), 1)
         laneless = fresh and self.voter_capacity > 64 and not self._use_pallas
@@ -986,6 +1033,7 @@ class ProposalPool:
         if not slots:
             return []
         self._check_no_inflight("timeout")
+        self._flush_activations()
         row_state = self._dispatch_timeout(np.asarray(slots, np.int32))
         out: list[tuple[int, int]] = []
         for i, slot in enumerate(slots):
@@ -1137,6 +1185,7 @@ class ProposalPool:
 
     def read_slot(self, slot: int) -> dict[str, np.ndarray]:
         """Gather one slot's full row back to host (debug / session export)."""
+        self._flush_activations()
         state, yes, tot, mask, vals = _read_kernel(
             self._state, self._yes, self._tot, self._vote_mask, self._vote_val,
             jnp.asarray(slot, jnp.int32),
@@ -1154,6 +1203,7 @@ class ProposalPool:
         many slots (arrays indexed [k] in ``slots`` order). The bulk-export
         path session demotion rides on — per-slot dispatches would make
         tier churn O(sessions) device round-trips."""
+        self._flush_activations()
         state, yes, tot, mask, vals = _read_kernel(
             self._state, self._yes, self._tot, self._vote_mask, self._vote_val,
             jnp.asarray(np.asarray(slots, np.int32)),

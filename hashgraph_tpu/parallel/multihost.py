@@ -368,6 +368,7 @@ class MultiHostPool(ShardedPool):
         if not slots:
             return []
         self._check_no_inflight("timeout")
+        self._flush_activations()
         slot_arr = np.asarray(slots, np.int64)
         slot_grid, _, rows, bucket = self._route(slot_arr, [])
         self._state, row_state = self._sharded_timeout(

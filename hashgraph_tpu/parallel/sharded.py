@@ -391,5 +391,6 @@ class ShardedPool(ProposalPool):
         (the all-reduce the host mirror makes redundant for small pools, but
         the scalable path for multi-host deployments where no single host
         sees every shard)."""
+        self._flush_activations()
         counts = np.asarray(self._sharded_counts(self._state))
         return {code: int(c) for code, c in zip(_STATE_CODES, counts)}
