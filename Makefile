@@ -7,7 +7,7 @@
 
 SHELL := /bin/bash
 
-.PHONY: verify tier1 dev-install test bench bench-redelivery bench-fleet bench-federation bench-catchup bench-gossip bench-reactor bench-chaos bench-liveness bench-churn bench-device-verify bench-slo-overhead bench-profile-overhead bench-regress fleet-smoke federation-smoke catchup-smoke gossip-smoke chaos-smoke liveness-smoke churn-smoke metrics-smoke trace-smoke federation-scrape-smoke slo-overhead-smoke profile-overhead-smoke profile-smoke smoke obs-smoke
+.PHONY: verify tier1 dev-install test catchup-smoke metrics-smoke trace-smoke federation-scrape-smoke smoke obs-smoke
 
 dev-install:
 	python -m pip install -e '.[dev]'
@@ -23,141 +23,11 @@ verify: dev-install tier1
 test:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow'
 
-bench:
-	python bench.py
-
-# Amortized-verification bench: gossip redelivery + incremental chain
-# growth, cache-on vs cache-off, real ECDSA (host-substrate sessions, so
-# it runs identically under JAX_PLATFORMS=cpu). The fast tier-1 smoke for
-# the same paths is tests/test_redelivery.py (stub signer).
-bench-redelivery:
-	python bench.py redelivery
-
-# Scope-sharded fleet bench: aggregate votes/sec across all local
-# devices, per-shard breakdown, paired fleet-vs-single-shard A/B with a
-# machine-readable noise_verdict, and a MULTICHIP-compatible record.
-bench-fleet:
-	python bench.py fleet
-
-# CI short run: 2 simulated shards on virtual CPU devices — exercises
-# fleet routing, the psum tally path, and the sweep on every PR.
-fleet-smoke:
-	JAX_PLATFORMS=cpu python bench.py fleet --smoke
-
-# Federated multi-host bench: N OS processes (one FleetGroup each —
-# examples/federation_host.py), two-level (host, shard) placement,
-# cross-host vote routing over coalesced OP_VOTE_BATCH frames, fabric
-# OP_FLEET_TALLY tallies, paired federated-vs-single-host A/B with a
-# machine-readable noise_verdict, and a LIVE SHARD MIGRATION under
-# sustained traffic (freeze -> snapshot+tail adopt -> fingerprint
-# equality -> atomic flip -> tail replay) with zero-lost-votes and
-# zero-lost-decisions asserts. HOSTS=N picks the host count.
-HOSTS ?= 2
-bench-federation:
-	JAX_PLATFORMS=cpu python bench.py fleet --hosts $(HOSTS)
-
-# CI short run: 2 OS processes on CPU, tiny shapes, one migration —
-# the whole federation surface (remote routing, tallies, migration,
-# typed retry-after window) on every PR.
-federation-smoke:
-	JAX_PLATFORMS=cpu python bench.py fleet --hosts 2 --smoke
-
-# State-sync catch-up bench: snapshot+tail vs full WAL replay at several
-# history lengths, paired same-window A/B with a machine-readable
-# noise_verdict, per-rep byte-identical convergence asserts.
-bench-catchup:
-	python bench.py catchup
-
-# CI short run: two in-process peers over a real bridge, small signed
+# State-sync check: two in-process peers over a real bridge, small signed
 # history, snapshot+tail AND full-replay joiners both asserted
 # byte-identical to the source, interrupted-transfer resume included.
 catchup-smoke:
 	JAX_PLATFORMS=cpu python examples/catchup_smoke.py
-
-# Networked gossip bench: N peers as separate OS processes over real TCP
-# (plus the shared-memory ring lane for the co-located case), aggregate
-# networked votes/sec, paired same-window A/B against the serial
-# BridgeClient loop with a machine-readable noise_verdict, per-rep
-# cross-peer state_fingerprint equality asserts, and per-rep wire-path
-# stage attribution (decode / crypto / device-apply seconds). STAGES=1
-# passes --stages explicitly; STAGES=0 drops the attribution block.
-STAGES ?= 1
-bench-gossip:
-	python bench.py gossip $(if $(filter 0,$(STAGES)),--no-stages,--stages)
-
-# Apply-reactor A/B bench: ONLY the paired reactor-off/on fabric arms on
-# dedicated peer sets (the reactor pinned per arm via gossip_peer.py
-# --reactor), gossip-frame-sized coalescer windows so the workload sits
-# in the many-small-dispatches regime the reactor amortizes. Reports a
-# noise_verdict, votes_per_dispatch per arm, and each arm's device-apply
-# share of server busy time vs the r06 66.8% attribution.
-bench-reactor:
-	python bench.py gossip --reactor-only
-
-# CI short run: 3 in-process peers — pipelining + coalescing + the
-# zero-copy columnar OP_VOTE_BATCH server path + a sampled-fanout
-# divergence healed by ONE anti-entropy round, final state
-# fingerprint-identical across peers. CI runs it twice: native parser
-# available, and HASHGRAPH_TPU_WIRE_COLUMNAR=0 forcing the pure-Python
-# object fallback path (which must stay green on its own).
-gossip-smoke:
-	JAX_PLATFORMS=cpu python bench.py gossip --smoke
-
-# Deterministic chaos harness, full depth: the scenario corpus
-# (partitions incl. asymmetric, drop/dup/reorder storms, kill-9
-# crash-restart via WAL recovery, lost-disk catch-up, equivocators,
-# forkers, expired-spam + signature-burst, liveness adversities) at 5
-# pinned seeds, four machine-checked verdicts per run (convergence,
-# exact-culprit accountability, honest-decision safety, liveness) + the
-# blindness self-test.
-bench-chaos:
-	JAX_PLATFORMS=cpu python bench.py chaos
-
-# CI short run: the same corpus at 3 pinned seeds. Seed-deterministic:
-# a failure here is a reproducible regression (re-run the same seed),
-# never a flake. The JSON line carries the machine-readable
-# `scenarios: {passed, failed, seeds}` block.
-chaos-smoke:
-	JAX_PLATFORMS=cpu python bench.py chaos --smoke
-
-# Liveness observatory, full depth: the Chandra–Toueg adversity trio
-# (flapping-links, slow-never-dead, stale-partial-synchrony) at 5 pinned
-# seeds with the φ-accrual A/B hard-gated — the adaptive watchdog must
-# suspect every flap the binary floor misses, zero stale convictions may
-# survive the heal in EITHER arm, and the tight-static counterfactual
-# must convict the slow-but-alive peer on every seed.
-bench-liveness:
-	JAX_PLATFORMS=cpu python bench.py liveness
-
-# CI short run: the same battery + A/B gates at 3 pinned seeds.
-# Seed-deterministic — a failure reproduces exactly from the seed in
-# the log, never a flake.
-liveness-smoke:
-	JAX_PLATFORMS=cpu python bench.py liveness --smoke
-
-# Tiered-session-lifecycle churn bench: 10M+ cumulative sessions through
-# a fixed-size engine with per-wave asserted RSS + device-slot + tier
-# ceilings (demote -> demand-page -> GC), paired same-window A/B against
-# an untier'd delete_scope arm with a machine-readable noise_verdict.
-bench-churn:
-	JAX_PLATFORMS=cpu python bench.py churn
-
-# CI short run: the same lifecycle (ceiling asserts ON, A/B included) at
-# a bounded cumulative-session count.
-churn-smoke:
-	JAX_PLATFORMS=cpu python bench.py churn --smoke
-
-# Device-vs-host-pool Ed25519 batch-verify A/B (the crypto_device
-# subsystem): same signed corpus through both verify_batch backends,
-# interleaved reps at 256/1k/4k/16k (SMOKE=1: 256/1k for CI), per-phase
-# device timings (decompress/SHA-512/MSM) and a machine-readable
-# noise_verdict that names the winner honestly — on CPU backends the
-# native pool wins; the device path is for accelerator hardware. The
-# persistent XLA compile cache (bench.py's default) keeps recompiles
-# from dominating repeat runs.
-SMOKE ?= 0
-bench-device-verify:
-	python bench.py device-verify $(if $(filter 1,$(SMOKE)),--smoke,)
 
 # End-to-end observability check: start a bridge server (WAL + HTTP
 # sidecar), drive a proposal to decision, scrape /metrics + /healthz and
@@ -181,45 +51,10 @@ trace-smoke:
 federation-scrape-smoke:
 	JAX_PLATFORMS=cpu python examples/federation_scrape_smoke.py
 
-# Always-on SLO tracking cost: paired interleaved A/B (SLO engine
-# enabled vs disabled) on a decision-heavy workload; the verdict holds
-# the median overhead under the 5% acceptance bar, noise-aware.
-bench-slo-overhead:
-	JAX_PLATFORMS=cpu python bench.py slo-overhead
-
-# CI short run of the same A/B at tiny shapes.
-slo-overhead-smoke:
-	JAX_PLATFORMS=cpu python bench.py slo-overhead --smoke
-
-# Always-on continuous-profiler cost: paired interleaved A/B (sampler
-# enabled vs parked, thread alive in both arms) on the same
-# decision-heavy workload; the verdict holds the median overhead under
-# the 2% acceptance bar, noise-aware.
-bench-profile-overhead:
-	JAX_PLATFORMS=cpu python bench.py profile-overhead
-
-# CI short run of the same A/B at tiny shapes.
-profile-overhead-smoke:
-	JAX_PLATFORMS=cpu python bench.py profile-overhead --smoke
-
-# End-to-end continuous-profiling check: the gossip smoke with the
-# always-on sampler armed via the env opt-in — every peer serves
-# OP_PROFILE, the bench merges the frames via merge_profile_states and
-# asserts the stage shares in-run (known names, sum <= 1.0).
-profile-smoke:
-	HASHGRAPH_TPU_PROFILE=1 JAX_PLATFORMS=cpu python bench.py gossip --smoke
-
-# Perf-regression sentry: reconstruct the BENCH_*.json trajectory and
-# issue noise-aware verdicts — exit 1 on a confident regression; drops
-# the recorded spreads cannot distinguish from noise stay advisory.
-# (`python bench.py regress` emits the same verdict as a bench line.)
-bench-regress:
-	python tools/bench_regress.py
-
 # Aggregate observability smoke: single-process scrape + trace paths.
 smoke: metrics-smoke trace-smoke
 
 # Fleet-wide observability plane smoke: everything `smoke` covers plus
-# the federated merged scrape and the SLO-overhead A/B — the CI
+# the federated merged scrape across two host processes — the CI
 # `obs-smoke` job's target.
-obs-smoke: smoke federation-scrape-smoke slo-overhead-smoke
+obs-smoke: smoke federation-scrape-smoke

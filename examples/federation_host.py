@@ -36,11 +36,11 @@ the parent closing the pipe is the shutdown signal:
         host's fleet -> ``SLOCFG``  (the SLO engine starts alerting on
         it; ``OP_METRICS_PULL`` / the merged ``/slo`` view report it)
     SLOSET <0|1>
-        Toggle the process-wide SLO engine (the overhead-A/B kill
-        switch) -> ``SLOSET <0|1>``
+        Toggle the process-wide SLO engine (the plane's kill switch)
+        -> ``SLOSET <0|1>``
 
-``bench.py fleet --hosts N`` spawns one of these per host; it is also a
-handy way to run a real multi-process federation by hand.
+``examples/federation_scrape_smoke.py`` spawns one of these per host;
+it is also a handy way to run a real multi-process federation by hand.
 """
 
 import argparse

@@ -12,9 +12,8 @@ crypto) as a real OS process:
 
 It prints ``PORT <port>`` on stdout once listening, then serves until
 stdin reaches EOF (the parent closing the pipe is the shutdown signal —
-no PID files, no signals racing the accept loop). ``bench.py gossip``
-spawns one of these per peer; it is also a handy way to run a real
-multi-process fabric by hand.
+no PID files, no signals racing the accept loop). Spawn one per peer
+to run a real multi-process fabric by hand.
 """
 
 import argparse

@@ -81,7 +81,7 @@ REQUIRED_FAMILIES = [
     # gauges plus demotion/promotion/GC counters. Eagerly installed — an
     # untier'd node's dashboard must still see them (at 0) before any
     # scope opts into TTL policies; the traffic is exercised by
-    # `bench.py churn` and tests/test_tiering.py.
+    # tests/test_tiering.py.
     "hashgraph_tier_demoted_sessions",
     "hashgraph_tier_bytes",
     "hashgraph_tier_demotions_total",
@@ -91,7 +91,8 @@ REQUIRED_FAMILIES = [
     # owned scopes over the fabric, shard migrations + their wall time.
     # Eagerly installed — a single-host node's dashboard must still see
     # them (at 0) before the operator ever federates; the traffic is
-    # exercised by `bench.py fleet --hosts 2` and tests/test_federation.py.
+    # exercised by examples/federation_scrape_smoke.py and
+    # tests/test_federation.py.
     "hashgraph_federation_hosts",
     "hashgraph_federation_remote_routed_votes_total",
     "hashgraph_federation_migrations_total",

@@ -154,9 +154,9 @@ OP_DELIVER_PROPOSALS = 20
 
 # STATE_FINGERPRINT: u32 peer_id -> string (hex). The peer engine's
 # order-insensitive content digest (sync.state_fingerprint) — the
-# convergence check the gossip bench/smoke asserts across peers that
-# live in DIFFERENT processes (in-process tests can reach the engine;
-# networked peers cannot).
+# convergence check a driver asserts across peers that live in
+# DIFFERENT processes (in-process tests can reach the engine; networked
+# peers cannot).
 OP_STATE_FINGERPRINT = 21
 
 # SHM_ATTACH (FEATURE_SHM_RING; pipelined connections only): u32

@@ -387,8 +387,8 @@ class BridgeServer:
         # (full validation, no per-vote Python objects); anything else —
         # and engines without the columnar entry point — takes the object
         # path, which stays the parity oracle. Default on; force off with
-        # wire_columnar=False or HASHGRAPH_TPU_WIRE_COLUMNAR=0 (the CI
-        # fallback leg runs the smoke that way).
+        # wire_columnar=False or HASHGRAPH_TPU_WIRE_COLUMNAR=0 (tests/
+        # test_gossip.py runs the served legs both ways).
         if wire_columnar is None:
             wire_columnar = os.environ.get(
                 "HASHGRAPH_TPU_WIRE_COLUMNAR", "1"

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives, for every entry point.
 
-One rule, shared by ``bench.py`` and ``chip_smoke.py``:
+One rule, which ``chip_smoke.py`` follows:
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads that directory itself and
   nothing here names another one.

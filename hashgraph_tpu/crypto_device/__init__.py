@@ -21,7 +21,7 @@ The public seam is NOT here: engines select the backend through
 ``HASHGRAPH_TPU_DEVICE_VERIFY`` env), and every caller keeps speaking
 ``SignatureScheme.verify_batch_submit`` / ``PendingVerdicts``. This
 package only exposes the backend entry points that seam calls, plus
-bench/test hooks.
+test hooks.
 
 Import note: submodules import JAX; this ``__init__`` defers those
 imports so ``hashgraph_tpu.signing`` (and the jax-free obs/WAL layers

@@ -27,8 +27,8 @@ combination fails. Verdicts are therefore decision-identical to
 Every batch increments ``hashgraph_device_verify_{batches,signatures}_
 total``; blame escalations increment ``..._fallbacks_total``; verify
 work lands in the ``hashgraph_device_verify_seconds`` histogram and the
-per-phase split is exported via :func:`last_phase_seconds` for the
-bench's BENCH-json timing block. The clocks measure WORK, not the wall
+per-phase split is exported via :func:`last_phase_seconds` for callers
+that time the phases. The clocks measure WORK, not the wall
 window: ``submit`` is host pack + device dispatch inside
 ``verify_batch_begin``; ``decompress``/``hash``/``msm``/``fallback``
 are time spent inside ``collect``; any overlap gap an async caller
@@ -75,7 +75,7 @@ def available() -> bool:
 
 
 def last_phase_seconds() -> "dict[str, float]":
-    """Per-phase wall seconds of the most recent batch (bench hook)."""
+    """Per-phase wall seconds of the most recent batch."""
     return dict(_last_phases)
 
 

@@ -765,8 +765,7 @@ class TpuConsensusEngine(Generic[Scope]):
         """TimelineStore slo_sink: one call per observed decision (same
         gating as the latency histogram). Resolves the scope's declared
         objective and forwards to the process SLO engine — a single
-        short-lock windowed-sketch update, cheap enough to stay always-on
-        (the <5% bound is held by bench.py's slo-overhead A/B)."""
+        short-lock windowed-sketch update, cheap enough to stay always-on."""
         cfg = self._scope_configs.get(tl.scope)
         objective = None
         if cfg is not None and cfg.decide_p99_ms is not None:
@@ -1932,10 +1931,9 @@ class TpuConsensusEngine(Generic[Scope]):
         aligned with ``votes``, exactly what the engine's own entry
         points consume. Embedders that drive :meth:`ingest_columnar`
         with pre-validated traffic use this to overlap batch k+1's
-        crypto with batch k's device ingest (the `bench.py
-        validated-sweep` cold path); verdicts must all be True and each
-        ``computed_hash`` must equal the vote's ``vote_hash`` before the
-        rows may be ingested as validated."""
+        crypto with batch k's device ingest; verdicts must all be True
+        and each ``computed_hash`` must equal the vote's ``vote_hash``
+        before the rows may be ingested as validated."""
         return self._cached_verify_begin(votes)
 
     def _cached_verify_begin(self, votes: "list[Vote]") -> "PendingVoteVerdicts":
@@ -3077,7 +3075,7 @@ class TpuConsensusEngine(Generic[Scope]):
         :func:`hashgraph_tpu.bridge.columnar.parse_vote_columns` over
         canonical rows ONLY (callers fall back to the object path for
         anything else). ``stage_seconds`` (optional dict) accumulates
-        ``"crypto"`` and ``"apply"`` wall seconds for the bench's stage
+        ``"crypto"`` and ``"apply"`` wall seconds for the bridge's stage
         attribution. ``_prepass`` accepts a
         :meth:`wire_verify_begin` started earlier (the pipelined bridge
         starts it on the reader thread); default recomputes it inline.

@@ -3,9 +3,8 @@
 The reference protocol gossips *growing vote chains*: a chain of length L
 delivered one extension at a time re-presents every earlier vote L times,
 and gossip redelivery re-presents whole chains verbatim. Signature
-verification is the engine's host-side wall (BENCHMARKS.md: ~92% of the
-validated end-to-end path is ECDSA), so re-verifying a vote that was
-already admitted — or already rejected — is the single largest avoidable
+verification is the engine's host-side wall, so re-verifying a vote that
+was already admitted — or already rejected — is the single largest avoidable
 cost under redelivery: O(L²) signature checks for an incrementally grown
 chain. This module memoizes the *signature verdict* per unique
 (vote content, signature) pair so that cost collapses to O(L).

@@ -315,9 +315,9 @@ BRIDGE_RETRY_AFTER_TOTAL = "hashgraph_bridge_retry_after_total"
 
 # Zero-copy wire ingest (bridge._op_vote_batch columnar fast path):
 # frames taken by each path, shm ring attachments, and per-stage wall
-# seconds (wire decode / crypto / device apply) — the attribution the
-# gossip bench reads back over GET_METRICS so the residual gap between
-# networked and in-process throughput stays explainable per stage.
+# seconds (wire decode / crypto / device apply) — the attribution
+# benchmark/run.py and obs.attribution read from the registry, so the
+# served path's busy time stays explainable per stage.
 WIRE_COLUMNAR_FRAMES_TOTAL = "hashgraph_bridge_wire_columnar_frames_total"
 WIRE_FALLBACK_FRAMES_TOTAL = "hashgraph_bridge_wire_fallback_frames_total"
 WIRE_DECODE_SECONDS_TOTAL = "hashgraph_bridge_wire_decode_seconds_total"
@@ -326,7 +326,7 @@ WIRE_APPLY_SECONDS_TOTAL = "hashgraph_bridge_wire_apply_seconds_total"
 SHM_RINGS_ATTACHED_TOTAL = "hashgraph_bridge_shm_rings_attached_total"
 # Device-dispatch amortization (ISSUE 19): how many fused
 # ingest_wire_columnar dispatches the bridge layer actually issued and
-# how many vote rows rode them — the bench's votes_per_dispatch line is
+# how many vote rows rode them — votes_per_dispatch is
 # apply_rows / device_dispatches, measured, not asserted. Both paths
 # (reactor on AND off) increment these at the engine-call site.
 WIRE_DEVICE_DISPATCHES_TOTAL = "hashgraph_bridge_wire_device_dispatches_total"

@@ -1,10 +1,8 @@
 """AttributionReport — the round-11/19 busy-share math as a readout.
 
-Round 11 found "device-apply is 66.8% of server busy time" and round 19
-attacked it down to 50.9% — but both numbers were ad-hoc counter
-arithmetic inside ``bench.py``. This module makes the per-component
-wall-clock attribution a first-class, machine-readable report any
-operator (or incident bundle) can pull:
+This module makes the per-component wall-clock attribution of the
+served path a first-class, machine-readable report any operator (or
+incident bundle) can pull:
 
 - the wire-path stage counters
   (``hashgraph_bridge_wire_{decode,crypto,apply}_seconds_total``),

@@ -864,8 +864,8 @@ class HealthMonitor:
     def snapshot(self, now: int | None = None) -> dict:
         """The full JSON-ready health report: scorecards (graded at
         ``now`` or the latest tick), evidence records, watchdog state,
-        and the firing alerts. This is what ``OP_HEALTH`` serves and
-        ``bench.py --health-out`` persists. The serialized state is the
+        and the firing alerts. This is what ``OP_HEALTH`` serves. The
+        serialized state is the
         SAME view the rules just evaluated (one pass, one moment — the
         report can never show alerts disagreeing with the scorecards
         beside them)."""

@@ -13,8 +13,7 @@ Design constraints, in the repo's established idiom:
 
 - **Opt-in like the reactor**: ``$HASHGRAPH_TPU_PROFILE=1`` arms the
   process-wide instance (``obs.default_profiler``); ``enabled = False``
-  is the live kill switch (the ``bench.py profile-overhead`` A/B flips
-  it), mirroring ``SloEngine.enabled``.
+  is the live kill switch, mirroring ``SloEngine.enabled``.
 - **Self-measuring overhead**: each sampling tick times itself and
   adapts the rate between ``min_hz`` (~19 Hz) and ``max_hz`` (~97 Hz) —
   backing off when the EWMA of its own cost exceeds ``overhead_budget``

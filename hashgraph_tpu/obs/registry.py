@@ -382,8 +382,7 @@ class MetricsRegistry:
 
     def snapshot(self) -> dict:
         """JSON-ready state: counter values, gauge samples, histogram
-        count/sum/quantiles. This is what ``bench.py --metrics-out``
-        persists next to the throughput numbers."""
+        count/sum/quantiles."""
         with self._lock:
             counters = list(self._counters.values())
             gauges = list(self._gauges.values())

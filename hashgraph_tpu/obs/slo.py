@@ -306,12 +306,12 @@ class SloEngine:
     under the caller's engine lock): it files the latency into the
     global, per-shard, and per-scope windowed sketches, applies the
     scope's objective if one was declared, and evaluates the alert state
-    machine. Scope trackers live in a bounded LRU (a churn bench mints
+    machine. Scope trackers live in a bounded LRU (session churn mints
     millions of scopes; unbounded per-scope state would be a leak) —
     scopes with declared objectives are pinned and never evicted.
 
     ``enabled=False`` short-circuits ``observe`` before any lock — the
-    kill switch the SLO-overhead A/B in ``bench.py`` flips."""
+    plane's kill switch."""
 
     def __init__(
         self,
@@ -480,7 +480,7 @@ class SloEngine:
 
     def _install_scope_gauges(self, key: str) -> None:
         # Only objective-carrying scopes get labelled families: those are
-        # operator-declared and few; minting one per churned bench scope
+        # operator-declared and few; minting one per churned scope
         # would grow the registry without bound (families are permanent).
         if self._registry is None or key in self._scope_gauges:
             return
@@ -609,7 +609,7 @@ class SloEngine:
         return out
 
     def reset(self) -> None:
-        """Drop every tracker (tests/bench reps; families persist)."""
+        """Drop every tracker (between tests; families persist)."""
         with self._lock:
             self._global = self._new_window()
             self._shards.clear()

@@ -1091,9 +1091,9 @@ def migrate_shard(
     unflipped, source unfrozen) on any integrity failure.
 
     This is the in-process orchestration (both groups in this process —
-    tests, smoke topologies). The multi-host bench drives the same
-    halves over the host runners' control channels with a
-    :class:`FederationDriver` buffering the in-window tail."""
+    tests, smoke topologies). Across processes, the same halves run over
+    each host's control channel (``examples/federation_host.py``) while
+    a :class:`FederationDriver` buffers the in-window tail."""
     from_host = placement.host_of(shard_id)
     if from_host == to_host:
         raise ValueError(f"shard {shard_id!r} already on {to_host!r}")
@@ -1157,9 +1157,9 @@ class FederationDriver:
     a migrating shard buffers into that shard's tail and replays after
     the placement flip).
 
-    This is ``bench.py fleet --hosts N``'s driver; it is also the shape
-    of a stateless front-end tier routing user traffic into the
-    federation."""
+    ``examples/federation_scrape_smoke.py`` drives two host processes
+    through it; it is also the shape of a stateless front-end tier
+    routing user traffic into the federation."""
 
     def __init__(
         self,
@@ -1352,7 +1352,7 @@ class FederationDriver:
         """Flush everything (windows, deferred resends) and await every
         in-flight frame; returns cumulative delivery counts since the
         last drain. ``acked == submitted - buffered`` (with zero
-        rejected) is the zero-loss criterion the bench asserts."""
+        rejected) is the zero-loss criterion."""
         deadline = time.monotonic() + timeout
         while True:
             for host in list(self._hosts):

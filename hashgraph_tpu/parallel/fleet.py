@@ -946,8 +946,8 @@ class ConsensusFleet:
 
     def occupancy(self) -> dict:
         """Per-shard breakdown: engine occupancy + per-device slot
-        occupancy (the MULTICHIP artifact's per-device view), plus the
-        shard's recovery provenance (see :meth:`_recovery_overlay`)."""
+        occupancy, plus the shard's recovery provenance (see
+        :meth:`_recovery_overlay`)."""
         out = {}
         for sid, shard in self._shards.items():
             if not shard.available:

@@ -6,15 +6,15 @@ Fleet and federation both need the fleet-wide sum, and before this
 helper each hand-summed its own key set — a new engine key (like the
 tier counters) could silently go missing from one aggregate. Now the
 key set lives here once: extend ``OCCUPANCY_SUM_KEYS`` and every
-aggregate surface (fleet totals, the federation adapter, bench
-rollups) carries the new counter automatically.
+aggregate surface (fleet totals, the federation adapter) carries the
+new counter automatically.
 
 The same discipline applies to cross-host METRIC federation:
 :func:`merge_metric_states` is the ONE merge for ``OP_METRICS_PULL``
 frames — fleet-wide totals plus per-host labelled breakdowns in the
 registry's export-state schema, renderable by
-``obs.prometheus.render_state`` — used by the federation driver's merged
-``/metrics`` view and ``bench.py``'s fleet reports alike. A second
+``obs.prometheus.render_state`` — used by the federation driver's and
+the host group's merged ``/metrics`` views alike. A second
 hand-sum anywhere means a new family can silently go missing from one
 surface; add behavior here instead.
 """
@@ -109,8 +109,8 @@ def merge_metric_states(frames) -> dict:
     - infos stay per-host only — constant metadata does not sum.
 
     The result renders with ``obs.prometheus.render_state`` — the one
-    merge + one renderer every fleet-wide surface (federation sidecar,
-    ``bench.py`` fleet reports) goes through.
+    merge + one renderer every fleet-wide surface (the federation
+    sidecar, the driver's merged view) goes through.
     """
     out = {"counters": {}, "gauges": {}, "histograms": {}, "infos": {}}
     skip_total: set = set()  # histogram families with mismatched bounds
@@ -186,7 +186,7 @@ def merge_profile_states(frames) -> dict:
     the fleet-wide amortization factor), and sample/role totals from
     every host's continuous profiler. The same discipline as
     :func:`merge_metric_states`: ONE merge, every fleet surface (the
-    federation sidecar's ``/profile``, bench reports) goes through it."""
+    federation sidecar's ``/profile``) goes through it."""
     from ..obs.attribution import STAGE_KEYS
 
     hosts: dict = {}

@@ -380,8 +380,8 @@ class ShardedPool(ProposalPool):
 
     def per_device_occupancy(self) -> list[int]:
         """Occupied (non-FREE) slots per mesh device, from the host state
-        mirror — the per-device view the MULTICHIP artifact and the fleet
-        bench's per-shard breakdown report. Device ``d`` owns the
+        mirror — the per-device view the fleet's per-shard breakdown
+        reports. Device ``d`` owns the
         contiguous block ``[d·local_capacity, (d+1)·local_capacity)``."""
         blocks = self._state_host.reshape(self.n_devices, self.local_capacity)
         return (blocks != STATE_FREE).sum(axis=1).astype(int).tolist()

@@ -11,11 +11,10 @@ inherently scalar — each signature costs a full double-scalar multiply
 and there is no sound way to merge checks — while Ed25519 verification
 equations combine algebraically: a random linear combination verifies a
 whole batch with one multi-scalar multiply (Bernstein et al., "Batch
-binary Edwards" lineage), which is what `bench.py validated-sweep`
-exercises. The native core (``native/consensus_native.cpp``) implements
-that batch path over the persistent verify pool; this module falls back
-to the pure-Python RFC 8032 code in :mod:`._ed25519` when the native
-runtime is absent.
+binary Edwards" lineage). The native core
+(``native/consensus_native.cpp``) implements that batch path over the
+persistent verify pool; this module falls back to the pure-Python RFC
+8032 code in :mod:`._ed25519` when the native runtime is absent.
 
 Verification is *cofactored* (accept iff ``8·(s·B - h·A - R)`` is the
 identity) with RFC 8032 canonical-encoding rejections — the only
@@ -253,8 +252,8 @@ class Ed25519DeviceConsensusSigner(Ed25519ConsensusSigner):
     def device_phase_seconds(cls) -> "dict[str, float]":
         """Per-phase wall seconds of the backend's most recent batch
         (decompress / hash / msm / fallback / total) — the engine's
-        wire-path stage attribution and the bench's timing block both
-        read this instead of re-instrumenting the pipeline."""
+        wire-path stage attribution reads this instead of
+        re-instrumenting the pipeline."""
         from .. import crypto_device
 
         return crypto_device.last_phase_seconds()

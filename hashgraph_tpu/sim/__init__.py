@@ -18,7 +18,7 @@ the injected culprits, with offline-verifiable evidence and zero honest
 convictions — the Polygraph/BFT-forensics bar), and **safety** (no two
 honest peers decide one session differently). ``run_corpus`` is the
 regression harness every future robustness/perf PR runs against
-(`bench.py chaos`, `make chaos-smoke`).
+(``tests/test_sim.py`` runs every scenario at pinned seeds).
 """
 
 from .byzantine import ByzantineActor, corrupt_vote_batch_signatures

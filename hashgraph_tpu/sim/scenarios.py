@@ -8,8 +8,8 @@ accountability, honest-decision safety, and bounded-decide /
 zero-stale-conviction liveness. ``run_scenario(name, seed)`` is
 a pure function of its arguments — same seed, byte-identical verdict
 JSON — which is what makes the corpus a regression harness rather than
-a demo: `bench.py chaos` and `make chaos-smoke` run it at pinned seeds,
-and any future PR that breaks a failure path breaks a deterministic
+a demo: ``tests/test_sim.py`` runs it at pinned seeds, and any
+future change that breaks a failure path breaks a deterministic
 assert, not a flake.
 
 The corpus (≥ the ISSUE's eight):
@@ -950,8 +950,8 @@ def run_scenario(
     ``Ed25519DeviceConsensusSigner`` to prove all four verdicts hold
     when rejects come from the device backend. ``overrides`` merges
     extra SimCluster kwargs over the spec's own — the liveness A/B in
-    ``bench.py`` uses ``{"phi_threshold": None}`` to run the
-    binary-watchdog-only baseline arm of the same scenario."""
+    ``tests/test_liveness.py`` uses ``{"phi_threshold": None}`` to run
+    the binary-watchdog-only baseline arm of the same scenario."""
     spec = SCENARIOS[name]
     kwargs = dict(spec.cluster_kwargs)
     if overrides:
@@ -982,9 +982,8 @@ def run_corpus(
     *,
     blind: bool = False,
 ) -> dict:
-    """The whole corpus × seeds -> the machine-readable summary block
-    ``bench.py chaos`` emits: {scenarios: {passed, failed, seeds},
-    results, failures}."""
+    """The whole corpus × seeds -> one machine-readable summary block:
+    {scenarios: {passed, failed, seeds}, results, failures}."""
     names = list(SCENARIOS) if names is None else list(names)
     results: dict[str, dict] = {}
     failures: list[dict] = []

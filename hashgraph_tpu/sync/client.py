@@ -309,7 +309,7 @@ class CatchUpClient:
     def full_replay(self, engine, *, tail_max_bytes: int = 0) -> CatchUpReport:
         """Catch up by streaming and applying the source's ENTIRE WAL —
         no snapshot, per-record validation all the way (the O(history)
-        baseline ``bench.py catchup`` measures snapshot+tail against).
+        baseline snapshot+tail is compared against).
         Only possible while the source's log is uncompacted from LSN 1;
         a compacted source raises :class:`TailGapError` — the signal that
         a snapshot is required."""

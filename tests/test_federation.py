@@ -3,10 +3,17 @@ TCP loopback — remote vote routing over the gossip fabric, cross-host
 tallies on the fabric path, and live shard migration under traffic with
 the typed retry-after window.
 
-(The multi-PROCESS topology — separate OS processes per host — is
-``bench.py fleet --hosts 2 --smoke``, the federation-smoke CI job;
-these tests exercise the same code with both groups in one process.)"""
+A :class:`FederationDriver` — the stateless front end with its own
+placement view, as a separate process would hold — routes votes into
+the same groups: zero loss across a migration whose in-window tail it
+buffers and replays, and the merged scrape and SLO view it pulls.
 
+(The multi-PROCESS topology — separate OS processes per host — is
+``examples/federation_scrape_smoke.py``; these tests exercise the same
+code with both groups in one process.)"""
+
+import json
+import os
 import threading
 import time
 
@@ -15,6 +22,7 @@ import pytest
 from hashgraph_tpu import CreateProposalRequest, StubConsensusSigner, build_vote
 from hashgraph_tpu.errors import StatusCode
 from hashgraph_tpu.parallel.federation import (
+    FederationDriver,
     FederationPlacement,
     FleetGroup,
     migrate_shard,
@@ -343,6 +351,120 @@ def test_live_migration_under_traffic(fresh_federation):
     assert groups["h1"].fleet.n_shards == 0
     for i in range(16):
         assert placement.owner(f"post-drain-{i}")[0] == "h0"
+
+
+def _driver(placement, groups):
+    driver = FederationDriver(placement, flush_votes=10**6, flush_interval=60.0)
+    for host, group in groups.items():
+        driver.connect(host, *group.address, group.peer_id)
+    return driver
+
+
+def test_driver_migration_replays_the_tail_with_zero_loss(fresh_federation):
+    """The driver's half of a live migration: votes still in its open
+    coalescer window when the freeze begins, and votes submitted during
+    the freeze, all buffer into the shard's tail and replay to the new
+    owner after the flip. Nothing is lost or rejected, and the session
+    decides on its new home at its last vote."""
+    placement, groups = fresh_federation
+    # The driver holds its own placement view, as a separate process would.
+    view = FederationPlacement.uniform(["h0", "h1"], 2)
+    scope = scope_owned_by(placement, "h1", tag="drv")
+    host, shard = placement.owner(scope)
+    request = CreateProposalRequest(
+        name="p", payload=b"", proposal_owner=b"o" * 20,
+        expected_voters_count=36, expiration_timestamp=3600,
+        liveness_criteria_yes=True,
+    )
+    proposal = groups[host].adapter.create_proposal(scope, request, NOW)
+    placement.pin(scope, shard)
+    view.pin(scope, shard)
+    votes = []
+    for i in range(24):  # quorum of exactly 24: the last vote decides
+        vote = build_vote(
+            proposal, True, StubConsensusSigner(bytes([80 + i]) * 20),
+            NOW + 1,
+        )
+        proposal.votes.append(vote)
+        votes.append(vote.encode())
+    driver = _driver(view, groups)
+    try:
+        assert driver.submit(scope, votes[:8], NOW + 2) == "sent"
+        first = driver.drain()
+        assert (first["acked"], first["rejected"]) == (8, 0), first
+        assert driver.submit(scope, votes[8:16], NOW + 2) == "sent"  # windowed
+        driver.begin_shard_migration(shard, retry_after=0.05)
+        assert driver.submit(scope, votes[16:], NOW + 2) == "buffered"
+        peer_id, src_fingerprint = groups["h1"].export_shard(shard, 0.05)
+        adopted = groups["h0"].adopt_shard(
+            shard, *groups["h1"].address, peer_id
+        )
+        assert adopted["fingerprint"] == src_fingerprint
+        placement.complete_migration(shard, "h0")
+        flip = driver.complete_shard_migration(shard, "h0")
+        assert flip["tail_votes"] == 16
+        groups["h1"].retire_shard(shard, peer_id)
+        report = driver.drain()
+    finally:
+        driver.close()
+    assert report["submitted"] == 16 and report["acked"] == 16, report
+    assert report["rejected"] == 0 and report["buffered"] == 0, report
+    assert view.owner(scope) == placement.owner(scope) == ("h0", shard)
+    result = groups["h0"].adapter.get_consensus_result(
+        scope, proposal.proposal_id
+    )
+    assert result is True
+
+
+def test_driver_merged_scrape_and_slo_breach(federation, tmp_path):
+    """Through the driver: one merged scrape carries both hosts'
+    labelled families and the decision histogram, an impossible
+    objective declared on a scope of each host fires that scope's alert
+    in the merged SLO view, and the breach leaves a trace-linked,
+    Perfetto-loadable incident dump."""
+    from hashgraph_tpu import ScopeConfigBuilder
+    from hashgraph_tpu.obs import slo_engine
+    from hashgraph_tpu.obs.slo import IncidentCapture
+
+    placement, groups = federation
+    saved_capture = slo_engine.capture
+    slo_engine.reset()
+    slo_engine.capture = IncidentCapture(str(tmp_path))
+    driver = _driver(placement, groups)
+    try:
+        probes = [
+            scope_owned_by(placement, host, tag=f"slo-{host}-")
+            for host in ("h0", "h1")
+        ]
+        for probe in probes:
+            owner, _shard = placement.owner(probe)
+            groups[owner].fleet.set_scope_config(
+                probe, ScopeConfigBuilder().with_decide_p99_ms(0.001).build()
+            )
+            _proposal, votes = make_session(placement, groups, probe)
+            driver.submit(probe, [v.encode() for v in votes], NOW + 2)
+        report = driver.drain()
+        assert report["acked"] == 6 and report["rejected"] == 0, report
+        text = driver.merged_metrics_text()
+        firing = driver.merged_slo()["alerts_firing"]
+        incidents = slo_engine.capture.incidents()
+    finally:
+        driver.close()
+        slo_engine.capture = saved_capture
+        slo_engine.reset()
+    assert 'host="h0"' in text and 'host="h1"' in text
+    assert "hashgraph_decision_latency_seconds_bucket" in text
+    for probe in probes:
+        assert any(alert.endswith(f"/{probe}") for alert in firing), firing
+    assert incidents
+    linked = []
+    for name in incidents:
+        with open(os.path.join(tmp_path, name, "incident.json")) as fh:
+            meta = json.load(fh)
+        with open(os.path.join(tmp_path, name, "trace.json")) as fh:
+            trace_doc = json.load(fh)
+        linked.append(bool(meta.get("trace_id")) and "traceEvents" in trace_doc)
+    assert any(linked), incidents
 
 
 def test_migrate_shard_unknown_target_leaves_topology_intact(federation):

@@ -228,6 +228,60 @@ def test_fleet_state_counts_psum_matches_host_mirrors(fleet):
     assert counts[STATE_FREE] == 32 * 4 - total
 
 
+def test_mixed_presets_sweep_and_tally_on_two_shards():
+    """A sustained mixed gossipsub/P2P workload on two shards: shuffled
+    columnar rows route to their owners with no unresolved id or stale
+    voter, scopes with an SLO objective and adaptive timeout bounds ride
+    the same path, and the timeout sweep settles every session so the
+    one-psum tally reports none left active."""
+    from hashgraph_tpu.ops.decide import STATE_ACTIVE, STATE_FREE
+
+    fleet = make_fleet(n_shards=2, capacity_per_shard=16, voter_capacity=16)
+    try:
+        rng = np.random.default_rng(31)
+        by_shard = scopes_covering_all_shards(fleet, per_shard=2, prefix="m")
+        scopes = [s for group in by_shard.values() for s in group]
+        for i, s in enumerate(scopes):
+            builder = ScopeConfigBuilder()
+            builder = builder.p2p_preset() if i % 2 else builder.gossipsub_preset()
+            builder = builder.with_decide_p99_ms(5_000.0)
+            if i // 2 % 2:  # each preset with and without timeout bounds
+                builder = builder.with_timeout_bounds(0.5, 30.0)
+            fleet.set_scope_config(s, builder.build())
+        per_scope = 6
+        pids = {
+            s: [
+                p.proposal_id
+                for p in fleet.create_proposals(
+                    s, [request(n=16, expiry=100)] * per_scope, NOW
+                )
+            ]
+            for s in scopes
+        }
+        present = 8
+        owners = [bytes([70 + i]) * 20 for i in range(present)]
+        rows = [
+            (k, pid, fleet.voter_gid(s, o))
+            for k, s in enumerate(scopes)
+            for pid in pids[s]
+            for o in owners
+        ]
+        order = rng.permutation(len(rows))
+        sidx, cpids, cgids = (np.array(col)[order] for col in zip(*rows))
+        st = fleet.ingest_columnar_multi(
+            scopes, sidx, cpids, cgids, rng.random(len(rows)) < 0.5, NOW
+        )
+        assert (st == int(StatusCode.OK)).all(), np.unique(st)
+        swept = fleet.sweep_timeouts(NOW + 200)
+        assert len(swept) == len(scopes) * per_scope
+        counts = fleet.fleet_state_counts()
+        assert fleet._tally() is not None  # the device psum path
+        assert counts.get(STATE_ACTIVE, 0) == 0
+        assert counts[STATE_FREE] == 2 * 16 - len(scopes) * per_scope
+    finally:
+        fleet.close()
+
+
 def test_occupancy_and_health_breakdown(fleet):
     by_shard = scopes_covering_all_shards(fleet, prefix="o")
     for group in by_shard.values():

@@ -552,12 +552,12 @@ class TestBackpressure:
 
 
 class TestGossipNode:
-    def _mesh(self, n):
+    def _mesh(self, n, **server_kwargs):
         servers, clients, peers = [], [], []
         for _ in range(n):
             srv = BridgeServer(
                 capacity=64, voter_capacity=12,
-                signer_factory=StubConsensusSigner,
+                signer_factory=StubConsensusSigner, **server_kwargs,
             )
             srv.start()
             cl = BridgeClient(*srv.address)
@@ -657,6 +657,74 @@ class TestGossipNode:
             assert second["redelivered"] == second["pushed_sessions"]
         finally:
             node.close()
+            self._teardown(servers, clients)
+
+    @pytest.mark.parametrize(
+        "server_kwargs",
+        [{}, {"wire_columnar": False}, {"apply_reactor": True}],
+        ids=["columnar", "object-fallback", "apply-reactor"],
+    )
+    def test_serial_fabric_and_repair_legs_converge(self, server_kwargs):
+        """The three ways a peer set receives votes — serial
+        OP_PROCESS_VOTES frames, the coalesced pipelined fabric, and a
+        sampled fan-out healed by one anti-entropy round — leave every
+        peer fingerprint-identical on each server-side vote path: the
+        zero-copy columnar parser, the object fallback, and the apply
+        reactor merging frames across connections."""
+        from hashgraph_tpu.engine import TpuConsensusEngine
+
+        servers, clients, peers = self._mesh(2, **server_kwargs)
+        local = TpuConsensusEngine(
+            StubConsensusSigner(b"local" + b"\x00" * 15),
+            capacity=64, voter_capacity=12,
+        )
+        fabric = GossipNode("driver", fanout=None)
+        repair = GossipNode("local", engine=local, fanout=1, seed=11)
+        try:
+            for i, srv in enumerate(servers):
+                fabric.add_peer(f"peer{i}", *srv.address, peers[i])
+                repair.add_peer(f"peer{i}", *srv.address, peers[i])
+
+            def distribute(scope, to_local=False):
+                _pid, blob, votes = make_chain(clients[0], peers[0], scope, 6)
+                clients[1].process_proposal(peers[1], scope, blob, NOW)
+                proposal = Proposal.decode(blob)
+                if to_local:
+                    local.process_incoming_proposal(scope, proposal, NOW)
+                return proposal.proposal_id, votes
+
+            def fingerprints():
+                return {
+                    cl.state_fingerprint(peer)
+                    for cl, peer in zip(clients, peers)
+                }
+
+            # Fan-out 1 reaches one server; one round repairs the other.
+            pid, votes = distribute("repair", to_local=True)
+            repair.submit_votes("repair", pid, votes, NOW + 1, local=True)
+            repair.drain()
+            assert len(fingerprints()) == 2  # the unsampled server lags
+            assert repair.anti_entropy(NOW + 1)["pushed_sessions"] >= 1
+            assert fingerprints() == {state_fingerprint(local)}
+
+            _pid, votes = distribute("serial")
+            statuses = [[], []]
+            for part in (votes[:4], votes[4:]):
+                for k, (cl, peer) in enumerate(zip(clients, peers)):
+                    statuses[k] += cl.process_votes(peer, "serial", part, NOW + 1)
+            assert statuses[0] == statuses[1]
+            assert len(fingerprints()) == 1
+
+            pid, votes = distribute("fabric")
+            for part in (votes[:4], votes[4:]):
+                fabric.submit_votes("fabric", pid, part, NOW + 1, local=False)
+            report = fabric.drain()
+            assert report["acked"] == 2 * len(votes), report
+            assert report["shed_total"] == 0, report
+            assert len(fingerprints()) == 1
+        finally:
+            fabric.close()
+            repair.close()
             self._teardown(servers, clients)
 
     def test_stalled_peer_sheds_then_recovers_via_anti_entropy(self):

@@ -430,9 +430,9 @@ def test_flapping_links_scenario_and_static_baseline_arm():
     assert 0 < live["max_decide_ticks"] <= live["decide_bound_ticks"]
     assert run["checks"]["phi_suspected_during_flap"]
 
-    # The A/B seam bench.py liveness rides: same scenario, binary-floor-
-    # only watchdog. All four verdicts still hold — the arm is blind to
-    # the flap (sub-floor silence), not broken.
+    # The A/B seam: same scenario, binary-floor-only watchdog. All four
+    # verdicts still hold — the arm is blind to the flap (sub-floor
+    # silence), not broken.
     base = run_scenario(
         "flapping-links", 7, overrides={"phi_threshold": None}
     )
@@ -451,3 +451,26 @@ def test_slow_never_dead_scenario_counterfactual():
     # have convicted it.
     assert run["checks"]["slow_peer_never_suspected"]
     assert run["checks"]["metronome_counterfactual_convicts"]
+
+
+@pytest.mark.parametrize("seed", [99, 1234])
+def test_liveness_ab_holds_at_pinned_seeds(seed):
+    """The two tests above, at the chaos corpus's other pinned seeds: φ
+    sees the flap the binary floor misses, neither arm leaves a stale
+    conviction after heal, and the tight static bar would convict the
+    slow-but-alive peer that φ keeps healthy."""
+    from hashgraph_tpu.sim import run_scenario
+
+    adaptive = run_scenario("flapping-links", seed)
+    static = run_scenario(
+        "flapping-links", seed, overrides={"phi_threshold": None}
+    )
+    assert adaptive["passed"], adaptive["checks"]
+    assert all(v["ok"] for v in static["verdicts"].values())
+    assert adaptive["checks"]["phi_suspected_during_flap"]
+    assert not static["checks"]["phi_suspected_during_flap"]
+    for run in (adaptive, static):
+        assert run["verdicts"]["liveness"]["stale_convictions"] == {}
+    slow = run_scenario("slow-never-dead", seed)
+    assert slow["passed"], slow["checks"]
+    assert slow["checks"]["metronome_counterfactual_convicts"]

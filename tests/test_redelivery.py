@@ -3,7 +3,7 @@ create-or-extend surface and its validated-chain watermark.
 
 Tier-1 smoke for the amortization layer (ISSUE 4): one redelivery wave
 with the stub signer exercises cache hits, the watermark suffix path,
-fork/truncation rejection, and bench.py's redelivery workload shape —
+fork/truncation rejection, and a grow-then-redeliver workload —
 without ``slow`` markers or real ECDSA.
 """
 
@@ -263,7 +263,7 @@ class TestDeliverProposals:
 
 class TestCacheOnOffEquivalence:
     def test_one_redelivery_wave_smoke(self):
-        """The bench.py redelivery shape in miniature, stub-signed: grow a
+        """A redelivery wave in miniature, stub-signed: grow a
         chain delivery by delivery, then redeliver every vote — cache-on
         and cache-off engines must report identical statuses and end in
         identical sessions."""

@@ -8,9 +8,10 @@ Covers the three layers separately, then the whole corpus:
 - the engine hardenings the harness forced — dangling-vote rejection
   and the double-sign fork-conviction bar (the defamation regression);
 - scenario acceptance — every corpus scenario passes all three verdicts
-  at a pinned seed, the SAME seed twice yields byte-identical verdict
-  JSON, and a deliberately blinded run (evidence layer disabled) FAILS
-  the accountability verdict — the harness can detect its own blindness.
+  at a pinned seed and every verdict at three more, the SAME seed twice
+  yields byte-identical verdict JSON, and a deliberately blinded run
+  (evidence layer disabled) FAILS the accountability verdict — the
+  harness can detect its own blindness.
 """
 
 import json
@@ -29,6 +30,7 @@ from hashgraph_tpu.sim import (
     SimNetwork,
     SimScheduler,
     SimTransport,
+    run_corpus,
     run_scenario,
     verify_evidence_record,
 )
@@ -38,6 +40,9 @@ from common import NOW
 
 
 SEED = 424242
+# Three more seeds every scenario must pass at: a scenario that holds at
+# one schedule only is a lucky interleaving, not a guarantee.
+PINNED_SEEDS = [7, 99, 1234]
 
 
 # ── core ───────────────────────────────────────────────────────────────
@@ -265,6 +270,12 @@ class TestScenarioCorpus:
         assert result["verdicts"]["accountability"]["ok"], result["verdicts"]
         assert result["verdicts"]["safety"]["ok"], result["verdicts"]
         assert result["passed"], result["checks"]
+
+    @pytest.mark.parametrize("name", sorted(SCENARIOS))
+    def test_scenario_passes_at_pinned_seeds(self, name):
+        corpus = run_corpus(PINNED_SEEDS, [name])
+        assert corpus["failures"] == [], corpus["failures"]
+        assert corpus["scenarios"]["passed"] == len(PINNED_SEEDS)
 
     def test_same_seed_yields_byte_identical_verdict_json(self):
         first = json.dumps(run_scenario("storm", SEED), sort_keys=True)

@@ -322,6 +322,44 @@ class TestEngineWiring:
         )
         slo_engine.reset()
 
+    def test_kill_switch_leaves_decisions_untouched(self):
+        """The global plane switched off: sessions on objective scopes
+        still decide, and the plane records none of it."""
+        from hashgraph_tpu import CreateProposalRequest, build_vote
+        from hashgraph_tpu.engine import TpuConsensusEngine
+        from hashgraph_tpu.obs import slo_engine
+        from hashgraph_tpu.scope_config import ScopeConfigBuilder
+
+        from common import NOW, random_stub_signer
+
+        slo_engine.reset()
+        engine = TpuConsensusEngine(
+            random_stub_signer(), capacity=8, voter_capacity=8
+        )
+        config = ScopeConfigBuilder().with_decide_p99_ms(0.001).build()
+        signers = [random_stub_signer() for _ in range(3)]
+        slo_engine.enabled = False
+        try:
+            for k in range(2):
+                scope = f"off-{k}"
+                engine.set_scope_config(scope, config)
+                request = CreateProposalRequest("p", b"", b"o", 3, 100, True)
+                pid = engine.create_proposal(scope, request, NOW).proposal_id
+                proposal = engine.get_proposal(scope, pid)
+                batch = []
+                for signer in signers:
+                    vote = build_vote(proposal, True, signer, NOW + 1)
+                    proposal.votes.append(vote)
+                    batch.append((scope, vote))
+                engine.ingest_votes(batch, NOW + 1)
+                assert engine.get_consensus_result(scope, pid) is True
+            state = slo_engine.state()
+        finally:
+            slo_engine.enabled = True
+            slo_engine.reset()
+        assert state["scopes"] == {} and state["global"]["count"] == 0
+        assert state["alerts_firing"] == []
+
 
 # ── Exemplars: render + text round-trip ────────────────────────────────
 

@@ -4,8 +4,8 @@ sources, and fleet catch_up_shard.
 
 Stub signers keep the suite fast (the scheme-independent machinery is
 under test); the scheme conformance suite already pins real crypto, and
-``bench.py catchup`` / ``make catchup-smoke`` exercise real signatures
-end to end.
+``make catchup-smoke`` runs the same catch-up end to end over a real
+bridge with real signatures.
 """
 
 import hashlib

@@ -346,6 +346,67 @@ class TestLifecyclePolicy:
         out = engine.lifecycle_sweep(NOW + 13)
         assert out["demoted"] == 1  # still idle since NOW+1, re-demotes
 
+    def test_churn_waves_hold_slot_and_population_ceilings(self):
+        """Waves of create -> decide -> demote-after-TTL -> GC-after-TTL
+        through a fixed-size engine: many times its slot capacity in
+        cumulative sessions, with the device-slot, live and tier
+        population ceilings held on EVERY wave."""
+        scopes, per_scope, voters = ["c0", "c1"], 8, 4
+        demote_after, evict_after = 2.0, 4.0
+        wave = len(scopes) * per_scope
+        # Live: waves inside the demotion window plus the in-flight one.
+        # Tier: waves between demotion and GC.
+        live_ceiling = wave * (int(demote_after) + 2)
+        tier_ceiling = wave * (int(evict_after - demote_after) + 2)
+        engine = _engine(
+            capacity=live_ceiling,
+            voter_capacity=voters,
+            max_sessions_per_scope=live_ceiling + tier_ceiling,
+        )
+        for scope in scopes:
+            engine.set_scope_config(
+                scope,
+                ScopeConfig(
+                    demote_after=demote_after, evict_decided_after=evict_after
+                ),
+            )
+        gids = np.array(
+            [engine.voter_gid(bytes([1 + i]) * 20) for i in range(voters)],
+            np.int64,
+        )
+        quorum = -(-2 * voters // 3)  # gossipsub default: div_ceil(2n, 3)
+        requests = [_request(n=voters, exp=10**8)] * per_scope
+        n_waves = 12
+        for k in range(n_waves):
+            now = NOW + k
+            batches = engine.create_proposals_multi(
+                [(scope, requests) for scope in scopes], now
+            )
+            pids = np.array(
+                [p.proposal_id for batch in batches for p in batch], np.int64
+            )
+            sidx = np.repeat(np.arange(len(scopes), dtype=np.int64), per_scope)
+            statuses = engine.ingest_columnar_multi(
+                scopes,
+                np.repeat(sidx, quorum),
+                np.repeat(pids, quorum),
+                np.tile(gids[:quorum], wave),
+                np.ones(wave * quorum, bool),
+                now,
+            )
+            assert (statuses == int(StatusCode.OK)).all(), np.unique(statuses)
+            engine.lifecycle_sweep(now)
+            occ = engine.occupancy()
+            assert occ["device_slots_used"] <= live_ceiling, (k, occ)
+            assert occ["live_sessions"] <= live_ceiling, (k, occ)
+            assert occ["tier_sessions"] <= tier_ceiling, (k, occ)
+        occ = engine.occupancy()
+        # The ceilings held because sessions really cycled through the
+        # tier and out of it, not because the engine stayed small.
+        assert n_waves * wave > 2 * live_ceiling
+        assert occ["tier_demotions_total"] > 0
+        assert occ["tier_gc_total"] > 0
+
 
 class TestCapEquivalence:
     def test_demoted_sessions_count_against_the_scope_cap(self):
